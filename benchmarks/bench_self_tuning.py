@@ -23,11 +23,14 @@ run the identical (deterministically seeded) op sequence:
   always_retrain  — full retrain on a fixed cadence, paying the whole-index
                     rebuild whether or not any shard needs it.
 
-Each policy runs in its OWN subprocess, so every policy pays its own cold
-jit-compile and reallocation debt — sharing one process would let whoever
-runs second reuse the first policy's compiled variants, which is exactly
-the cost axis the policies differ on. Reported throughput covers the FULL
-run: maintenance, reallocation and recompilation included.
+The policies run one after another in this process (a chip belongs to
+one process, so a child could not open it). Before each one
+``jax.clear_caches()`` empties the jit caches and the persistent
+compilation cache is off, so every policy pays its own cold jit-compile
+and reallocation debt — otherwise whoever runs second would reuse the
+first policy's compiled variants, which is exactly the cost axis the
+policies differ on. Reported throughput covers the FULL run:
+maintenance, reallocation and recompilation included.
 
 Per-wave serving-path latency (lookup + insert + range scans + the
 between-wave tuner hook) is recorded per policy; the ``async_vs_sync`` row
@@ -50,11 +53,6 @@ from __future__ import annotations
 
 import argparse
 import hashlib
-import json
-import os
-import subprocess
-import sys
-import tempfile
 import time
 
 import numpy as np
@@ -225,28 +223,15 @@ def _run_policy(
     return res
 
 
-def _spawn_policy(policy: str, ns) -> dict:
-    """Run one policy in a clean subprocess (own jit cache) and collect."""
-    with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
-        out_path = f.name
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
-    )
-    cmd = [
-        sys.executable, "-m", "benchmarks.bench_self_tuning",
-        "--policy", policy, "--out", out_path,
-        "--n-keys", str(ns.n_keys), "--waves", str(ns.waves),
-        "--batch", str(ns.batch), "--shards", str(ns.shards),
-        "--retrain-every", str(ns.retrain_every), "--seed", str(ns.seed),
-        "--replay-cap", str(ns.replay_cap),
-    ]
-    try:
-        subprocess.run(cmd, check=True, timeout=1800, env=env)
-        with open(out_path) as fh:
-            return json.load(fh)
-    finally:
-        os.unlink(out_path)
+def _run_cold(policy: str, init, plan, **kw) -> dict:
+    """Run one policy with empty jit caches and no persistent cache."""
+    import jax
+
+    from repro import compile_cache
+
+    jax.clear_caches()
+    with compile_cache.disabled():
+        return _run_policy(policy, init, plan, **kw)
 
 
 def run(
@@ -260,11 +245,14 @@ def run(
 ):
     from benchmarks.common import emit
 
-    ns = argparse.Namespace(
-        n_keys=n_keys, waves=waves, batch=batch, shards=n_shards,
-        retrain_every=retrain_every, seed=seed, replay_cap=replay_cap,
-    )
-    results = {p: _spawn_policy(p, ns) for p in POLICIES}
+    init, plan, _ = _workload(n_keys, waves, batch, seed)
+    results = {
+        p: _run_cold(
+            p, init, plan, n_shards=n_shards, retrain_every=retrain_every,
+            seed=seed, replay_cap=replay_cap,
+        )
+        for p in POLICIES
+    }
     max_tput = max(r["ops_per_s"] for r in results.values())
     max_mem = max(r["index_bytes"] for r in results.values())
     rows = []
@@ -401,8 +389,6 @@ def run(
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--policy", choices=POLICIES, default=None)
-    ap.add_argument("--out", default=None)
     ap.add_argument("--n-keys", type=int, default=200_000)
     ap.add_argument("--waves", type=int, default=90)
     ap.add_argument("--batch", type=int, default=4096)
@@ -411,21 +397,14 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--replay-cap", type=int, default=2048)
     args = ap.parse_args()
-    if args.policy is None:
-        run(
-            n_keys=args.n_keys, waves=args.waves, batch=args.batch,
-            n_shards=args.shards, retrain_every=args.retrain_every,
-            seed=args.seed, replay_cap=args.replay_cap,
-        )
-        return
-    init, plan, _ = _workload(args.n_keys, args.waves, args.batch, args.seed)
-    res = _run_policy(
-        args.policy, init, plan,
+    from repro import compile_cache
+
+    compile_cache.enable()
+    run(
+        n_keys=args.n_keys, waves=args.waves, batch=args.batch,
         n_shards=args.shards, retrain_every=args.retrain_every,
         seed=args.seed, replay_cap=args.replay_cap,
     )
-    with open(args.out, "w") as fh:
-        json.dump(res, fh)
 
 
 if __name__ == "__main__":

@@ -16,6 +16,7 @@ from benchmarks.common import emit, index_classes
 from repro.core import ShardedUpLIF, UpLIF
 from repro.core.uplif import UpLIFConfig
 from repro.data import WORKLOADS, WorkloadRunner, make_dataset
+from repro.kernels.ops import MAX_VMEM_KEYS
 
 DATASETS = ("wikits", "logn", "fb")
 
@@ -110,7 +111,13 @@ def run_locate_sweep(
     ever loses to it). Interleaved rounds, medians; off-TPU the fused
     rows run the kernels in interpret mode, so they prove the wiring
     rather than the TPU win — the decomposition delta is real either way,
-    since the split cost is jnp, not kernel, work."""
+    since the split cost is jnp, not kernel, work.
+
+    Every variant stays inside the fused kernels' shape guard: a delta
+    buffer of at most MAX_VMEM_KEYS keys in all, and radix_bits=14 so the
+    S=4 radix tables fit. Each row is checked against the strategy the
+    index reports before and after its timed rounds, so a row named fused
+    never times the spline path."""
     rng = np.random.default_rng(seed)
     keys = make_dataset("wikits", n_keys, seed)
     init = keys[::2]
@@ -126,8 +133,8 @@ def run_locate_sweep(
     indexes = {}
     for name, strat, s, decomp in variants:
         cfg = UpLIFConfig(
-            bmat_capacity=n_keys, locate=strat,
-            persist_halves=decomp != "percall",
+            bmat_capacity=min(n_keys, MAX_VMEM_KEYS), radix_bits=14,
+            locate=strat, persist_halves=decomp != "percall",
         )
         indexes[name] = (
             UpLIF(init, init + 1, cfg)
@@ -135,6 +142,15 @@ def run_locate_sweep(
             else ShardedUpLIF(init, init + 1, cfg, n_shards=s)
         )
 
+    def check_strategies():
+        for name, strat, s, _ in variants:
+            idx = indexes[name]
+            ran = (
+                (idx.locate_strategy(),) if s == 1 else idx.shard_locate()
+            )
+            assert set(ran) == {strat}, f"{name} runs {ran}"
+
+    check_strategies()
     qs = rng.choice(init, batch).astype(np.int64)
     for idx in indexes.values():
         idx.lookup(qs)  # compile outside the timed rounds
@@ -158,6 +174,7 @@ def run_locate_sweep(
             t0 = time.perf_counter()
             indexes[name].insert(c, c + 1)
             ins[name].append(time.perf_counter() - t0)
+    check_strategies()
 
     rows = []
     for op, samples in (("lookup", look), ("insert", ins)):

@@ -36,6 +36,9 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         bench_bmat_types,
         bench_gateway,

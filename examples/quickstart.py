@@ -4,12 +4,14 @@
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import UpLIF
 from repro.core.rl_agent import AgentConfig, QLearningAgent
 from repro.data import WorkloadRunner, make_dataset
 
 
 def main():
+    compile_cache.enable()
     print("== UpLIF quickstart ==")
     keys = make_dataset("logn", 200_000)
     runner = WorkloadRunner(keys, init_frac=0.5, seed=0)

@@ -20,6 +20,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ShardedUpLIF
 from repro.data import make_dataset
 from repro.serve import GatewayConfig, RequestGateway, RetryAfter
@@ -56,6 +57,7 @@ def client_loop(gw, keys, hist, stop, tid, counts):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--keys", type=int, default=200_000)
     ap.add_argument("--clients", type=int, default=64)

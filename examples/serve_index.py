@@ -17,6 +17,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ShardedUpLIF
 from repro.data import WORKLOADS, WorkloadRunner, make_dataset
 from repro.tuning import SelfTuner
@@ -25,6 +26,7 @@ WAVE = 4096  # ops per request wave
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--keys", type=int, default=1_000_000)
     ap.add_argument("--seconds", type=float, default=8.0)

@@ -8,6 +8,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 import repro.core  # noqa: F401
 from repro.configs import smoke_config
 from repro.models import init_params
@@ -16,6 +17,7 @@ from repro.serve.engine import Request
 
 
 def main():
+    compile_cache.enable()
     cfg = smoke_config("deepseek-7b")
     params = init_params(cfg, 0)
     eng = ServeEngine(cfg, params, max_len=256)

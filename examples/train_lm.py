@@ -10,6 +10,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro import compile_cache
 import repro.core  # noqa: F401 — x64 (index subsystem)
 from repro.configs import smoke_config
 from repro.data.pipeline import PackedCorpus, PipelineConfig
@@ -20,6 +21,7 @@ from repro.train.step import make_train_step
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-7b")
     ap.add_argument("--steps", type=int, default=60)

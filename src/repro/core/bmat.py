@@ -132,6 +132,23 @@ def _lookup(keys, vals, ranks, queries):
 
 
 @jax.jit
+def prefix_count(mark: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum of an int32 0/1 marker, as int64.
+
+    Log-step doubling (Hillis-Steele) instead of ``jnp.cumsum``: the TPU
+    compiler's cumsum lowering takes seconds to minutes to compile at
+    BMAT sizes (and its int64 form far longer), while log2(n) shifted adds
+    compile in under a second and cost a few extra passes over a
+    marker array. Exact while the count fits int32."""
+    n = mark.shape[0]
+    x = mark.astype(jnp.int32)
+    s = 1
+    while s < n:
+        x = x + jnp.concatenate([jnp.zeros((s,), jnp.int32), x[:-s]])
+        s *= 2
+    return x.astype(jnp.int64)
+
+
 def _merge(
     keys: jnp.ndarray,
     vals: jnp.ndarray,
@@ -161,7 +178,7 @@ def _merge(
     new_at = jnp.full((cap,), -1, dtype=jnp.int32).at[tgt].set(
         jnp.arange(q, dtype=jnp.int32), mode="drop"
     )
-    nb = jnp.cumsum(mark)  # new entries at merged positions <= i (inclusive)
+    nb = prefix_count(mark)  # new entries at merged positions <= i (inclusive)
     i = jnp.arange(cap, dtype=jnp.int64)
     is_new = new_at >= 0
     old_idx = jnp.clip(i - nb, 0, cap - 1)

@@ -35,7 +35,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.bmat import RBMAT, _make_fences, _merge, _rank_bpmat, _rank_rbmat
+from repro.core.bmat import (
+    RBMAT,
+    _make_fences,
+    _merge,
+    _rank_bpmat,
+    _rank_rbmat,
+    prefix_count,
+)
 from repro.core.radix_spline import _rs_predict_impl
 from repro.core.state import (
     LOCATE_BINSEARCH,
@@ -66,6 +73,29 @@ class RangeResult(NamedTuple):
 # ---------------------------------------------------------------------------
 
 
+def fused_fits(slot_keys, model, bmat: BMATState) -> bool:
+    """Static guard of the fused strategy: True when both fused kernels
+    (locate and BMAT rank) admit these array shapes — single-shard
+    ([cap]) or stacked ([S, cap]). Callers resolve ``locate`` against it
+    (``state.resolve_locate``) before building an ``UpLIFStatic``, so a
+    static that says fused always runs the kernels."""
+    S = slot_keys.shape[0] if slot_keys.ndim == 2 else 1
+    return kops.locate_fusable(
+        slot_keys.shape[-1], model.spline_keys.shape[-1],
+        model.table.shape[-1], S,
+    ) and kops.rank_fusable(
+        S * bmat.keys.shape[-1], S * bmat.fences.shape[-1]
+    )
+
+
+def _require_fusable(ok: bool):
+    if not ok:
+        raise ValueError(
+            "locate='fused' at shapes the fused kernels refuse; resolve the "
+            "strategy with state.resolve_locate(..., fits=fused_fits(...))"
+        )
+
+
 def _locate(static: UpLIFStatic, slot_keys, model, queries, halves=None):
     """(j, ins_cap): j = index of the last slot with key <= q (-1 if below
     all keys); ins_cap = largest slot index an insert derived from this
@@ -94,13 +124,13 @@ def _locate(static: UpLIFStatic, slot_keys, model, queries, halves=None):
         lo, hi = jax.lax.fori_loop(0, n_iters, body, (lo, hi))
         return lo - 1, jnp.full(queries.shape, cap - 1, dtype=jnp.int64)
 
-    if static.locate == LOCATE_FUSED and kops.locate_fusable(
-        cap, model.spline_keys.shape[0], model.table.shape[0], 1
-    ):
+    if static.locate == LOCATE_FUSED:
         # Fused Pallas hot path: radix predict + knot search + interpolation
         # + the SAME drift-proof 3-row bounded search below, in one kernel
-        # launch (interpret mode off-TPU). Shapes outside the VMEM guard
-        # fall through to the jnp spline path — same span, same j.
+        # launch (interpret mode off-TPU) — same span, same j.
+        _require_fusable(kops.locate_fusable(
+            cap, model.spline_keys.shape[0], model.table.shape[0], 1
+        ))
         return kops.fused_locate(
             model.table, model.spline_keys, model.spline_pos,
             model.shift.reshape(1), slot_keys, queries,
@@ -163,13 +193,12 @@ def _probe(slot_keys, slot_vals, slot_occ, j, queries):
 def _bmat_rank(static: UpLIFStatic, bmat: BMATState, queries, halves=None):
     """searchsorted-left rank over the packed BMAT (layout per static)."""
     cap = bmat.keys.shape[0]
-    if static.locate == LOCATE_FUSED and kops.rank_fusable(
-        cap, bmat.fences.shape[0]
-    ):
+    if static.locate == LOCATE_FUSED:
         # Definition 1 bias query r(k) through the fused two-level kernel.
         # The rank is an exact integer search, so this is byte-identical to
         # the jnp fence/node bisects for BOTH BMAT kinds (the fence arrays
         # are maintained regardless of the traversal the jnp path uses).
+        _require_fusable(kops.rank_fusable(cap, bmat.fences.shape[0]))
         return kops.bmat_rank_fused(
             bmat.keys, bmat.fences, queries,
             jnp.zeros(queries.shape, dtype=jnp.int64),
@@ -312,9 +341,17 @@ def _inplace_window_insert(
     # ---- fill-forward repair (vectorized suffix-min) ---------------------
     # For a sorted window, an empty slot's fill key = min occupied key at or
     # after it; if none in-window, the (unchanged) boundary fill of the last
-    # slot applies. Both collapse to one reverse cummin.
-    m = jnp.where(n_o, n_k, jnp.asarray(KEY_MAX, n_k.dtype))
-    suffix_min = jnp.flip(jax.lax.cummin(jnp.flip(m, axis=1), axis=1), axis=1)
+    # slot applies. Both collapse to one suffix-min, taken by log-step
+    # doubling: the TPU compiler needs over a minute for an int64
+    # ``lax.cummin`` and about a second for log2(W) shifted minimums.
+    suffix_min = jnp.where(n_o, n_k, jnp.asarray(KEY_MAX, n_k.dtype))
+    s = 1
+    while s < W:
+        suffix_min = jnp.minimum(suffix_min, jnp.concatenate(
+            [suffix_min[:, s:], jnp.full((n_k.shape[0], s), KEY_MAX, n_k.dtype)],
+            axis=1,
+        ))
+        s *= 2
     boundary = n_k[:, W - 1 :]
     n_k = jnp.minimum(suffix_min, boundary)
 
@@ -387,6 +424,18 @@ def _merge_pending(static, bmat: BMATState, keys, vals, pending, n_bmat_live,
     return out, n_bmat_live + revived + n_new, jnp.sum(pending), bmat_halves
 
 
+def _next_locate(i, rounds, locate, sk, slot_halves, pending, j, icap):
+    """(j, ins_cap) for insert round ``i + 1``: a fresh locate over the
+    slots round ``i`` rewrote, skipped after the last round."""
+    if rounds == 1:
+        return j, icap
+    return jax.lax.cond(
+        i + 1 < rounds,
+        lambda: locate(sk, slot_halves, pending)[1:],
+        lambda: (j, icap),
+    )
+
+
 @functools.partial(
     jax.jit, static_argnames=("static", "check_bmat", "merge_overflow")
 )
@@ -401,11 +450,13 @@ def insert(
 ):
     """Batched upsert, fully on-device. ``keys`` is KEY_MAX-padded.
 
-    Round structure (static.insert_rounds, unrolled):
-      1. locate + probe: keys already in place get a value update (incl.
-         tombstone revival); keys live in the BMAT get updated there
-         (round 1 only — the pending set can't gain such keys mid-call);
-      2. grid-segment accept: each pending key maps to the W-aligned window
+    Structure:
+      1. locate + probe, once: keys already in place get a value update
+         (incl. tombstone revival); keys live in the BMAT get updated there
+         (the pending set can't gain such keys mid-call);
+      2. static.insert_rounds rounds (a loop, compiled once) of
+         grid-segment accept, each on a fresh locate (round 0 on the
+         probe's): each pending key maps to the W-aligned window
          holding its insertion slot; the first pending key of each segment
          is accepted — segments are disjoint, so all accepted windows run
          through one vectorized bounded-shift + fill-forward repair.
@@ -426,36 +477,44 @@ def insert(
 
     pending = (keys != KEY_MAX) & ~_dedup_last_wins(keys)
     n_keys, n_bmat_live = c.n_keys, c.n_bmat_live
-    n_inplace, min_gran = c.n_inplace, c.min_granularity
 
-    for rnd in range(max(1, static.insert_rounds)):
-        if halves is not None:
-            halves = halves._replace(
-                slot_hi=slot_halves[0], slot_lo=slot_halves[1]
-            )
+    def locate(sk, slot_halves, pending):
+        h = halves
+        if h is not None:
+            h = h._replace(slot_hi=slot_halves[0], slot_lo=slot_halves[1])
         qk = jnp.where(pending, keys, KEY_MAX)
-        j, icap = _locate(static, sk, state.model, qk, halves=halves)
-        if rnd == 0:
-            # upsert keys already in the slot array (revives tombstones)
-            hit, alive, _, jj = _probe(sk, sv, so, j, qk)
-            n_keys = n_keys + jnp.sum(hit & ~alive)
-            sv = sv.at[jnp.where(hit, jj, cap + 1)].set(vals, mode="drop")
-            pending = pending & ~hit
-            if check_bmat:
-                # keys live in the BMAT -> value update there
-                ranks = _bmat_rank(static, bmat, qk, halves=halves)
-                _, b_alive, _, bidx = _bmat_probe(bmat, ranks, qk)
-                upd = b_alive & pending
-                bcap = bmat.keys.shape[0]
-                bvals = bmat.vals.at[jnp.where(upd, bidx, bcap + 1)].set(
-                    vals, mode="drop"
-                )
-                bmat = bmat._replace(vals=bvals)
-                pending = pending & ~upd
-            qk = jnp.where(pending, keys, KEY_MAX)
-            j = jnp.where(pending, j, cap - 1)
+        j, icap = _locate(static, sk, state.model, qk, halves=h)
+        return qk, j, icap
 
-        # ---- grid-segment accept (the on-device greedy replacement) ------
+    # upsert keys already in the slot array (revives tombstones)
+    qk, j, icap = locate(sk, slot_halves, pending)
+    hit, alive, _, jj = _probe(sk, sv, so, j, qk)
+    n_keys = n_keys + jnp.sum(hit & ~alive)
+    sv = sv.at[jnp.where(hit, jj, cap + 1)].set(vals, mode="drop")
+    pending = pending & ~hit
+    if check_bmat:
+        # keys live in the BMAT -> value update there
+        ranks = _bmat_rank(static, bmat, qk, halves=halves)
+        _, b_alive, _, bidx = _bmat_probe(bmat, ranks, qk)
+        upd = b_alive & pending
+        bcap = bmat.keys.shape[0]
+        bvals = bmat.vals.at[jnp.where(upd, bidx, bcap + 1)].set(
+            vals, mode="drop"
+        )
+        bmat = bmat._replace(vals=bvals)
+        pending = pending & ~upd
+
+    rounds = max(1, static.insert_rounds)
+
+    def accept_round(i, carry):
+        """One grid-segment accept (the on-device greedy replacement), run
+        as a loop body so the TPU compiler compiles it once, not once per
+        round. Round 0 reuses the probe's locate (the slot keys have not
+        moved since); each round locates for the next one, except the
+        last, so a wave runs ``rounds`` locates in all."""
+        (sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran,
+         j, icap) = carry
+        qk = jnp.where(pending, keys, KEY_MAX)
         # clamp to the locate span so a boundary the bounded search could
         # not prove lands in the BMAT, never outside the searched rows
         ins_slot = jnp.clip(jnp.minimum(j + 1, icap), 0, cap - 1)
@@ -476,10 +535,20 @@ def insert(
         )
         ok = can & pend_s
         n_ok = jnp.sum(ok)
-        n_inplace = n_inplace + n_ok
-        n_keys = n_keys + n_ok
-        min_gran = jnp.minimum(min_gran, jnp.min(failed_span))
         pending = pending & ~jnp.zeros(n, dtype=bool).at[order].set(ok)
+        j, icap = _next_locate(i, rounds, locate, sk, slot_halves, pending,
+                               j, icap)
+        return (sk, sv, so, slot_halves, pending, n_keys + n_ok,
+                n_inplace + n_ok, jnp.minimum(min_gran, jnp.min(failed_span)),
+                j, icap)
+
+    sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran, _, _ = (
+        jax.lax.fori_loop(
+            0, rounds, accept_round,
+            (sk, sv, so, slot_halves, pending, n_keys, c.n_inplace,
+             c.min_granularity, j, icap),
+        )
+    )
 
     if halves is not None:
         halves = halves._replace(
@@ -708,12 +777,13 @@ def _locate_stacked(static: UpLIFStatic, slot_keys, model, q, sid,
         lo, hi = jax.lax.fori_loop(0, n_iters, body, (lo, hi))
         return lo - 1, jnp.full(q.shape, cap - 1, dtype=jnp.int64)
 
-    if static.locate == LOCATE_FUSED and kops.locate_fusable(
-        cap, model.spline_keys.shape[1], model.table.shape[1], S
-    ):
+    if static.locate == LOCATE_FUSED:
         # ONE kernel launch for all S shards: arrays flatten over the shard
         # axis and every query carries its base offsets (sid * dim), so S
         # stays amortized to zero exactly like the flat jnp variants.
+        _require_fusable(kops.locate_fusable(
+            cap, model.spline_keys.shape[1], model.table.shape[1], S
+        ))
         return kops.fused_locate(
             model.table.reshape(-1), model.spline_keys.reshape(-1),
             model.spline_pos.reshape(-1), model.shift,
@@ -820,9 +890,8 @@ def _bmat_rank_stacked(static: UpLIFStatic, bmat: BMATState, q, sid,
     S, cap = bmat.keys.shape
     kflat = bmat.keys.reshape(-1)
     base = sid * cap
-    if static.locate == LOCATE_FUSED and kops.rank_fusable(
-        S * cap, S * bmat.fences.shape[1]
-    ):
+    if static.locate == LOCATE_FUSED:
+        _require_fusable(kops.rank_fusable(S * cap, S * bmat.fences.shape[1]))
         return kops.bmat_rank_fused(
             kflat, bmat.fences.reshape(-1), q, sid,
             cap=cap, nf=bmat.fences.shape[1], fanout=static.fanout,
@@ -1025,7 +1094,7 @@ def _merge_pending_stacked(static, bmat: BMATState, keys, vals, pending, sid,
     new_at = jnp.full((S * bcap,), -1, dtype=jnp.int32).at[tgt].set(
         jnp.arange(N, dtype=jnp.int32), mode="drop"
     )
-    cum = jnp.cumsum(mark).reshape(S, bcap)
+    cum = prefix_count(mark).reshape(S, bcap)
     seg_base = jnp.concatenate([jnp.zeros(1, cum.dtype), cum[:-1, -1]])
     nb = cum - seg_base[:, None]
     i = jnp.arange(bcap, dtype=jnp.int64)[None, :]
@@ -1095,43 +1164,54 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
 
     pending = (keys != KEY_MAX) & ~_dedup_last_wins(keys)
     n_keys, n_bmat_live = c.n_keys, c.n_bmat_live
-    n_inplace, min_gran = c.n_inplace, c.min_granularity
 
-    for rnd in range(max(1, static.insert_rounds)):
-        slots2 = SlotsState(
-            keys=sk.reshape(S, cap), vals=sv.reshape(S, cap),
-            occ=so.reshape(S, cap),
-        )
-        if halves is not None:
-            halves = halves._replace(
+    def locate(sk, slot_halves, pending):
+        h = halves
+        if h is not None:
+            h = h._replace(
                 slot_hi=slot_halves[0].reshape(S, cap),
                 slot_lo=slot_halves[1].reshape(S, cap),
             )
         qk = jnp.where(pending, keys, KEY_MAX)
         j, icap = _locate_stacked(
-            static, slots2.keys, state.model, qk, sid,
-            halves=halves, codes=codes,
+            static, sk.reshape(S, cap), state.model, qk, sid,
+            halves=h, codes=codes,
         )
-        if rnd == 0:
-            hit, alive, _, jj = _probe_stacked(slots2, j, qk, sid)
-            n_keys = n_keys + _seg_add(S, sid, hit & ~alive)
-            sv = sv.at[jnp.where(hit, sid * cap + jj, S * cap + 1)].set(
-                vals, mode="drop"
-            )
-            ranks = _bmat_rank_stacked(
-                static, bmat, qk, sid, halves=halves, codes=codes
-            )
-            _, b_alive, _, bidx = _bmat_probe_stacked(bmat, ranks, qk, sid)
-            upd = b_alive & pending
-            bcap = bmat.keys.shape[1]
-            bvals = bmat.vals.reshape(-1).at[
-                jnp.where(upd, sid * bcap + bidx, S * bcap + 1)
-            ].set(vals, mode="drop").reshape(S, bcap)
-            bmat = bmat._replace(vals=bvals)
-            pending = pending & ~hit & ~upd
-            qk = jnp.where(pending, keys, KEY_MAX)
+        return qk, j, icap
 
-        # ---- global grid-segment accept over the flat view ---------------
+    # ---- upsert keys already in the slot array or live in the BMAT ------
+    qk, j, icap = locate(sk, slot_halves, pending)
+    slots2 = SlotsState(
+        keys=sk.reshape(S, cap), vals=sv.reshape(S, cap),
+        occ=so.reshape(S, cap),
+    )
+    hit, alive, _, jj = _probe_stacked(slots2, j, qk, sid)
+    n_keys = n_keys + _seg_add(S, sid, hit & ~alive)
+    sv = sv.at[jnp.where(hit, sid * cap + jj, S * cap + 1)].set(
+        vals, mode="drop"
+    )
+    ranks = _bmat_rank_stacked(
+        static, bmat, qk, sid, halves=halves, codes=codes
+    )
+    _, b_alive, _, bidx = _bmat_probe_stacked(bmat, ranks, qk, sid)
+    upd = b_alive & pending
+    bcap = bmat.keys.shape[1]
+    bvals = bmat.vals.reshape(-1).at[
+        jnp.where(upd, sid * bcap + bidx, S * bcap + 1)
+    ].set(vals, mode="drop").reshape(S, bcap)
+    bmat = bmat._replace(vals=bvals)
+    pending = pending & ~hit & ~upd
+
+    rounds = max(1, static.insert_rounds)
+
+    def accept_round(i, carry):
+        """One global grid-segment accept over the flat view. The rounds
+        run as a loop, not unrolled: the TPU compiler then compiles the
+        round once instead of once per round. Locates as in ``insert``:
+        the probe's serves round 0, and the last round locates nothing."""
+        (sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran,
+         j, icap) = carry
+        qk = jnp.where(pending, keys, KEY_MAX)
         ins_slot = jnp.clip(jnp.minimum(j + 1, icap), 0, cap - 1)
         bucket = jnp.where(
             pending, sid * nw_per + ins_slot // W, jnp.int64(S * nw_per + 1)
@@ -1151,14 +1231,22 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
         ok = can & ps
         sid_w = jnp.clip(bs // nw_per, 0, S - 1)
         ok_per = _seg_add(S, sid_w, ok)
-        n_inplace = n_inplace + ok_per
-        n_keys = n_keys + ok_per
         span_per = jnp.full((S,), _I64_MAX).at[
             jnp.where(failed_span < _I64_MAX, sid_w, S)
         ].min(failed_span, mode="drop")
-        min_gran = jnp.minimum(min_gran, span_per)
-        done = jnp.zeros(N, dtype=bool).at[order].set(ok)
-        pending = pending & ~done
+        pending = pending & ~jnp.zeros(N, dtype=bool).at[order].set(ok)
+        j, icap = _next_locate(i, rounds, locate, sk, slot_halves, pending,
+                               j, icap)
+        return (sk, sv, so, slot_halves, pending, n_keys + ok_per,
+                n_inplace + ok_per, jnp.minimum(min_gran, span_per), j, icap)
+
+    sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran, _, _ = (
+        jax.lax.fori_loop(
+            0, rounds, accept_round,
+            (sk, sv, so, slot_halves, pending, n_keys, c.n_inplace,
+             c.min_granularity, j, icap),
+        )
+    )
 
     if halves is not None:
         halves = halves._replace(

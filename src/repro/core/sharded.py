@@ -57,10 +57,17 @@ import numpy as np
 from repro.core import fops
 from repro.core.bmat import BMAT, BPMAT, RBMAT, _make_fences, bmat_height
 from repro.core.shapes import grow_capacity, pow2_at_least
-from repro.core.state import UpLIFState, UpLIFStatic, make_halves, resolve_locate
+from repro.core.state import (
+    LOCATE_FUSED,
+    LOCATE_STRATEGIES,
+    UpLIFState,
+    UpLIFStatic,
+    make_halves,
+    resolve_locate,
+)
 from repro.core.types import BMATState, GMMState, KEY_MAX, SlotsState
 from repro.core.uplif import UpLIF, UpLIFConfig, bucket_width
-from repro.kernels.ops import on_tpu, split_key
+from repro.kernels.ops import split_key
 
 
 # --------------------------------------------------------------------------
@@ -441,15 +448,17 @@ class ShardedUpLIF:
         self._revisions: List[Tuple[int, int, int]] = []  # (ordinal, lo, hi)
         self._next_build_id = 0
         # -- per-shard locate-strategy axis --------------------------------
-        # every shard starts on the resolved config strategy; the telemetry-
-        # driven controller flips individual shards via set_shard_locate.
-        # _locate_value/_jcodes are the cached dispatch form consumed by
-        # _static()/_read_view() (see _set_locate_axis).
-        self._locate_per_shard: List[str] = (
-            [resolve_locate(self.cfg.locate, on_tpu())] * self.n_shards
+        # every shard starts on the platform-resolved config strategy; the
+        # telemetry-driven controller flips individual shards via
+        # set_shard_locate. _locate_requested is what was asked for;
+        # _locate_per_shard is what runs, held to the fused kernels' shape
+        # guard at the current stacked shapes. _locate_value/_jcodes are the
+        # cached dispatch form consumed by _static()/_read_view() (see
+        # _set_locate_axis; every restack and BMAT growth refreshes them).
+        self._locate_requested: List[str] = (
+            [resolve_locate(self.cfg.locate)] * self.n_shards
         )
         self._locate_obs: List[Tuple[np.ndarray, float, Tuple[str, ...]]] = []
-        self._set_locate_axis()
         self._restack(shells)
 
     # -- stacking ------------------------------------------------------------
@@ -514,6 +523,7 @@ class ShardedUpLIF:
                 getattr(self, "rs_iters", 0),
             )
             self._meta = meta
+            self._set_locate_axis()
         assert cap % W == 0
 
     def _pad_shell(
@@ -590,7 +600,11 @@ class ShardedUpLIF:
 
     # -- per-shard locate dispatch ---------------------------------------------
     def _set_locate_axis(self):
-        """Refresh the cached dispatch form of ``_locate_per_shard``.
+        """Resolve ``_locate_requested`` against the fused kernels' shape
+        guard at the current stacked shapes into ``_locate_per_shard`` (the
+        strategies that execute: a shard asking for fused where the guard
+        refuses runs — and reports — spline), then refresh its cached
+        dispatch form.
 
         ``_locate_value`` is what ``_static().locate`` carries: the single
         strategy string when the assignment is uniform (the common case —
@@ -598,8 +612,13 @@ class ShardedUpLIF:
         tuple of distinct strategies in play, so the static universe stays
         inside the ≤7-value family regardless of which shard runs what.
         ``_jcodes`` is the traced companion: per-shard int32 indices into
-        that tuple (None when uniform). Callers mutate ``_locate_per_shard``
-        under the lock and call this before releasing it."""
+        that tuple (None when uniform). Callers mutate ``_locate_requested``
+        or the state's shapes under the lock and call this before releasing
+        it."""
+        fits = self._fused_fits()
+        self._locate_per_shard = [
+            resolve_locate(r, fits) for r in self._locate_requested
+        ]
         distinct = sorted(set(self._locate_per_shard))
         if len(distinct) == 1:
             self._locate_value = distinct[0]
@@ -619,20 +638,30 @@ class ShardedUpLIF:
         strategy never changes what a query returns (the three strategies
         are byte-identical by the equivalence contract), so — unlike
         ``switch_bmat_type`` — this records NO revision and needs no
-        in-flight-build veto. Returns True when the assignment changed."""
+        in-flight-build veto. Returns True when the strategy that executes
+        changed (fused where the shape guard refuses still runs spline)."""
         assert 0 <= s < self.n_shards
-        strategy = resolve_locate(strategy, on_tpu())
+        strategy = resolve_locate(strategy)
         with self._lock:
-            if self._locate_per_shard[s] == strategy:
-                return False
-            self._locate_per_shard[s] = strategy
+            before = self._locate_per_shard[s]
+            self._locate_requested[s] = strategy
             self._set_locate_axis()
-        return True
+            return self._locate_per_shard[s] != before
+
+    def _fused_fits(self) -> bool:
+        st = self.state
+        return fops.fused_fits(st.slots.keys, st.model, st.bmat)
 
     def shard_locate(self) -> Tuple[str, ...]:
-        """Current per-shard strategy assignment (telemetry snapshot input)."""
+        """Per-shard strategies that execute (telemetry snapshot input)."""
         with self._lock:
             return tuple(self._locate_per_shard)
+
+    def locate_options(self) -> Tuple[str, ...]:
+        """Strategies a shard can run at the current stacked shapes."""
+        if self._fused_fits():
+            return LOCATE_STRATEGIES
+        return tuple(x for x in LOCATE_STRATEGIES if x != LOCATE_FUSED)
 
     def drain_locate_obs(
         self,
@@ -646,8 +675,8 @@ class ShardedUpLIF:
 
     def _static(self) -> UpLIFStatic:
         # cfg.locate is resolved per shard at init/set_shard_locate time
-        # ("auto" -> fused on TPU / spline elsewhere), so router ops and
-        # host-side maintenance replay run the same strategies
+        # ("auto" -> spline) and held to the fused
+        # guard at every shape change (_set_locate_axis)
         return UpLIFStatic(
             window=self.cfg.window,
             movement_k=self.cfg.movement_k,
@@ -936,6 +965,7 @@ class ShardedUpLIF:
                 ),
                 halves=halves,
             )
+            self._set_locate_axis()
 
     # -- versioned-state protocol (plan/build/commit; DESIGN.md §8) ------------
     @property
@@ -1202,8 +1232,7 @@ class ShardedUpLIF:
                 self.n_shards += 1
                 self.n_splits += 1
                 # both halves inherit the split shard's locate strategy
-                self._locate_per_shard.insert(s, self._locate_per_shard[s])
-                self._set_locate_axis()
+                self._locate_requested.insert(s, self._locate_requested[s])
                 self._restack(live[:s] + list(shells) + live[s + 1:])
         elif delta.kind == "merge":
             live = [self._unstack_shell(i) for i in range(self.n_shards)]
@@ -1213,8 +1242,7 @@ class ShardedUpLIF:
                 self.n_shards -= 1
                 self.n_merges += 1
                 # the merged shard keeps the left member's strategy
-                del self._locate_per_shard[s + 1]
-                self._set_locate_axis()
+                del self._locate_requested[s + 1]
                 self._restack(live[:s] + list(shells) + live[s + 2:])
         else:
             raise ValueError(f"unknown delta kind: {delta.kind}")
@@ -1297,8 +1325,7 @@ class ShardedUpLIF:
             self._jbounds = jnp.asarray(self.boundaries)
             self.n_shards += 1
             self.n_splits += 1
-            self._locate_per_shard.insert(s, self._locate_per_shard[s])
-            self._set_locate_axis()
+            self._locate_requested.insert(s, self._locate_requested[s])
             self._restack(shells[:s] + [left, right] + shells[s + 1:])
             self._record_revision(lo, hi)
         return True
@@ -1326,8 +1353,7 @@ class ShardedUpLIF:
             self._jbounds = jnp.asarray(self.boundaries)
             self.n_shards -= 1
             self.n_merges += 1
-            del self._locate_per_shard[s + 1]
-            self._set_locate_axis()
+            del self._locate_requested[s + 1]
             self._restack(shells[:s] + [merged] + shells[s + 2:])
             self._record_revision(lo, hi)
         return True
@@ -1363,6 +1389,7 @@ class ShardedUpLIF:
                 ),
                 halves=halves,
             )
+            self._set_locate_axis()
         return True
 
     # -- accounting ------------------------------------------------------------

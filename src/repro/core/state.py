@@ -29,27 +29,31 @@ _I64_MAX = np.iinfo(np.int64).max
 LOCATE_SPLINE = "spline"      # radix-spline predict + bounded window bisect
 LOCATE_BINSEARCH = "binsearch"  # model-free full bisect (B+Tree baseline)
 LOCATE_FUSED = "fused"        # fused Pallas predict+search kernel (hot path)
-LOCATE_AUTO = "auto"          # resolve per platform (fused on TPU)
+LOCATE_AUTO = "auto"          # the default strategy (spline)
 
 LOCATE_STRATEGIES = (LOCATE_SPLINE, LOCATE_BINSEARCH, LOCATE_FUSED)
 
 
-def resolve_locate(requested: str, on_tpu: bool) -> str:
-    """Map a configured locate strategy to a concrete one.
+def resolve_locate(requested: str, fits: bool = True) -> str:
+    """Map a configured locate strategy to the one that will run.
 
-    ``LOCATE_AUTO`` picks the fused Pallas kernels on TPU (where the single
-    kernel launch amortizes predict + bounded search + interpolation) and
-    the jnp spline path elsewhere — off-TPU the kernels only run in
-    interpret mode, which is a correctness proxy, not a speedup. Explicit
-    strategies pass through validated, so tests/benches can pin interpret-
-    mode fused on CPU."""
+    ``LOCATE_AUTO`` picks the jnp spline path on every platform: the
+    fused kernels walk one query at a time on the TPU's scalar unit and
+    ran warm lookup waves about 3.6x slower than spline on a v5e (S=1,
+    200k keys; PERF.md §5), so fused is an explicit choice. Explicit strategies pass through validated, so
+    tests/benches can pin interpret-mode fused on CPU. ``fits`` is the
+    fused kernels' static shape guard for the state at hand
+    (``fops.fused_fits``): where it refuses, fused resolves to spline, so
+    the resolved name is always the code that executes."""
     if requested == LOCATE_AUTO:
-        return LOCATE_FUSED if on_tpu else LOCATE_SPLINE
+        requested = LOCATE_SPLINE
     if requested not in LOCATE_STRATEGIES:
         raise ValueError(
             f"unknown locate strategy {requested!r}; "
             f"expected one of {LOCATE_STRATEGIES + (LOCATE_AUTO,)}"
         )
+    if requested == LOCATE_FUSED and not fits:
+        return LOCATE_SPLINE
     return requested
 
 

@@ -223,10 +223,12 @@ class UpLIF:
 
     def locate_strategy(self) -> str:
         """Concrete locate strategy for this call: the class override (the
-        baselines' hook) wins, then cfg.locate with platform resolution."""
-        from repro.kernels.ops import on_tpu
-
-        return resolve_locate(self.LOCATE or self.cfg.locate, on_tpu())
+        baselines' hook) wins, then cfg.locate ("auto" resolved), held to
+        the fused kernels' guard at the current shapes."""
+        return resolve_locate(
+            self.LOCATE or self.cfg.locate,
+            fops.fused_fits(self.slots.keys, self.rs_model, self.bmat.state),
+        )
 
     def fstatic(self) -> UpLIFStatic:
         """Hashable static config for the fops suite."""

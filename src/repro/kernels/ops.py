@@ -1,9 +1,11 @@
 """jit'd dispatch wrappers around the Pallas kernels.
 
 Handles: int64 -> (hi:int32, lo:uint32) decomposition, padding to kernel
-block sizes, platform selection (interpret mode off-TPU), and the big-buffer
-fallback composition for bmat_rank. Each wrapper is numerically validated
-against repro.kernels.ref in tests/test_kernels.py.
+block sizes, platform selection (``interpret_mode``: the one place that
+decides whether kernels compile for the TPU or run in Pallas interpret
+mode), and the big-buffer fallback composition for bmat_rank. Each wrapper
+is numerically validated against repro.kernels.ref in
+tests/test_kernels.py.
 """
 from __future__ import annotations
 
@@ -14,10 +16,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.kernels.bmat_rank import OFF_Q_BLK, bmat_rank_offset_pallas
+from repro.kernels.bmat_rank import bmat_rank_offset_pallas
 from repro.kernels.gmm_estep import N_BLK as GMM_N_BLK, gmm_estep_pallas
+from repro.kernels.rows import pad_queries
 from repro.kernels.spline_lookup import (
-    LOC_Q_BLK,
     Q_BLK as SPL_Q_BLK,
     fused_locate_pallas,
     spline_lookup_pallas,
@@ -33,6 +35,12 @@ def on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
+def interpret_mode() -> bool:
+    """Kernels compile for the TPU on a TPU and run in Pallas interpret
+    mode everywhere else (CPU tests); no kernel entry has a default."""
+    return not on_tpu()
+
+
 def split_key(k: jnp.ndarray):
     """int64 key -> (hi:int32, lo:uint32); exact for the 52-bit domain and
     for the KEY_MAX sentinel ordering (hi compares first)."""
@@ -43,10 +51,15 @@ def split_key(k: jnp.ndarray):
 
 def _pad_to(x: jnp.ndarray, mult: int, fill):
     n = x.shape[0]
-    m = ((n + mult - 1) // mult) * mult
+    return _pad_len(x, -(-n // mult) * mult, fill), n
+
+
+def _pad_len(x: jnp.ndarray, m: int, fill):
+    """Pad [n] to length ``m`` with ``fill``."""
+    n = x.shape[0]
     if m == n:
-        return x, n
-    return jnp.concatenate([x, jnp.full((m - n,), fill, x.dtype)]), n
+        return x
+    return jnp.concatenate([x, jnp.full((m - n,), fill, x.dtype)])
 
 
 # -- spline lookup ----------------------------------------------------------
@@ -54,7 +67,6 @@ def _pad_to(x: jnp.ndarray, mult: int, fill):
 
 def spline_lookup(table, spline_keys, spline_pos, shift, queries, n_iters):
     """Batched learned-index predict (float32 positions)."""
-    interpret = not on_tpu()
     sk_hi, sk_lo = split_key(spline_keys)
     q_hi, q_lo = split_key(queries)
     sp = spline_pos.astype(jnp.float32)
@@ -69,7 +81,7 @@ def spline_lookup(table, spline_keys, spline_pos, shift, queries, n_iters):
     else:
         out = spline_lookup_pallas(
             table, sk_hi, sk_lo, sp, q_hi, q_lo,
-            shift=int(shift), n_iters=n_iters, interpret=interpret,
+            shift=int(shift), n_iters=n_iters, interpret=interpret_mode(),
         )
     return out[:n]
 
@@ -100,7 +112,6 @@ def route_and_search(slot_keys, queries, pred_pos):
     Returns j = index of last slot key <= q, assuming the true position is
     inside the predicted tile +- 1 (guaranteed by the model error bound; the
     caller widens to neighbor tiles on miss)."""
-    interpret = not on_tpu()
     cap = slot_keys.shape[0]
     n_tiles = (cap + TILE - 1) // TILE
     padded_cap = n_tiles * TILE
@@ -125,7 +136,7 @@ def route_and_search(slot_keys, queries, pred_pos):
         tiles_lo,
         buf_hi.reshape(n_tiles, TS_Q_BLK),
         buf_lo.reshape(n_tiles, TS_Q_BLK),
-        interpret=interpret,
+        interpret=interpret_mode(),
     ).reshape(-1)
     local = out[flat]
     j_sorted = t_sorted * TILE + local.astype(jnp.int64)
@@ -173,7 +184,6 @@ def fused_locate(
     (hi, lo) conversion is skipped entirely (the int64 source arrays are
     then dead inputs that XLA eliminates). Only the O(batch) query split
     stays per-call. Without them the split runs here, per call."""
-    interpret = not on_tpu()
     L = min(3 * window, cap)
     if spline_hi is None:
         spline_hi, spline_lo = split_key(spline_keys)
@@ -181,25 +191,18 @@ def fused_locate(
         slot_hi, slot_lo = split_key(slot_keys)
     if spline_pos32 is None:
         spline_pos32 = spline_pos.astype(jnp.float32)
-    sk_hi, sk_lo = spline_hi, spline_lo
-    sl_hi, sl_lo = slot_hi, slot_lo
     q_hi, q_lo = split_key(queries)
-    sp32 = spline_pos32
-    tb = (sid * n_table).astype(jnp.int32)
-    sb = (sid * n_knots).astype(jnp.int32)
-    slb = (sid * cap).astype(jnp.int32)
-    sh = shift.astype(jnp.int32)[sid]
-    q_hi, n = _pad_to(q_hi, LOC_Q_BLK, np.iinfo(np.int32).max)
-    q_lo, _ = _pad_to(q_lo, LOC_Q_BLK, np.iinfo(np.uint32).max)
-    tb, _ = _pad_to(tb, LOC_Q_BLK, 0)
-    sb, _ = _pad_to(sb, LOC_Q_BLK, 0)
-    slb, _ = _pad_to(slb, LOC_Q_BLK, 0)
-    sh, _ = _pad_to(sh, LOC_Q_BLK, 32)
+    n = queries.shape[0]
+    m = pad_queries(n)
+    i32 = lambda a, fill: _pad_len(a.astype(jnp.int32), m, fill)
     j, start = fused_locate_pallas(
-        table, sk_hi, sk_lo, sp32, sl_hi, sl_lo,
-        q_hi, q_lo, tb, sb, slb, sh,
+        table, spline_hi, spline_lo, spline_pos32, slot_hi, slot_lo,
+        _pad_len(q_hi, m, np.iinfo(np.int32).max),
+        _pad_len(q_lo, m, np.iinfo(np.uint32).max),
+        i32(sid * n_table, 0), i32(sid * n_knots, 0), i32(sid * cap, 0),
+        i32(shift.astype(jnp.int32)[sid], 32),
         n_table=n_table, n_knots=n_knots, cap=cap, window=window,
-        rs_iters=rs_iters, interpret=interpret,
+        rs_iters=rs_iters, interpret=interpret_mode(),
     )
     j = j[:n].astype(jnp.int64)
     icap = start[:n].astype(jnp.int64) + (L - 1)
@@ -222,23 +225,20 @@ def bmat_rank_fused(keys, fences, queries, sid, *, cap: int, nf: int,
     shard-local searchsorted-left rank as int32 (callers widen). Pre-split
     halves (``keys_hi``..``fences_lo``, from a persistent ``state.halves``)
     skip the per-call buffer decomposition; only queries split here."""
-    interpret = not on_tpu()
     if keys_hi is None:
         keys_hi, keys_lo = split_key(keys)
     if fences_hi is None:
         fences_hi, fences_lo = split_key(fences)
-    kh, kl = keys_hi, keys_lo
-    fh, fl = fences_hi, fences_lo
     qh, ql = split_key(queries)
-    kb = (sid * cap).astype(jnp.int32)
-    fb = (sid * nf).astype(jnp.int32)
-    qh, n = _pad_to(qh, OFF_Q_BLK, np.iinfo(np.int32).max)
-    ql, _ = _pad_to(ql, OFF_Q_BLK, np.iinfo(np.uint32).max)
-    kb, _ = _pad_to(kb, OFF_Q_BLK, 0)
-    fb, _ = _pad_to(fb, OFF_Q_BLK, 0)
+    n = queries.shape[0]
+    m = pad_queries(n)
     out = bmat_rank_offset_pallas(
-        kh, kl, fh, fl, qh, ql, kb, fb,
-        cap=cap, nf=nf, fanout=fanout, interpret=interpret,
+        keys_hi, keys_lo, fences_hi, fences_lo,
+        _pad_len(qh, m, np.iinfo(np.int32).max),
+        _pad_len(ql, m, np.iinfo(np.uint32).max),
+        _pad_len((sid * cap).astype(jnp.int32), m, 0),
+        _pad_len((sid * nf).astype(jnp.int32), m, 0),
+        cap=cap, nf=nf, fanout=fanout, interpret=interpret_mode(),
     )
     return out[:n]
 
@@ -262,7 +262,6 @@ def _bmat_rank_tiled(keys, queries):
     kh, kl = split_key(sk)
     tiles_hi = kh.reshape(n_tiles, TILE)
     tiles_lo = kl.reshape(n_tiles, TILE)
-    interpret = not on_tpu()
 
     qm1 = queries - 1  # keys are non-negative: q - 1 >= -1 orders below all
     tile_id = np.clip(
@@ -289,7 +288,7 @@ def _bmat_rank_tiled(keys, queries):
                 tiles_hi, tiles_lo,
                 jnp.asarray(buf_hi.reshape(n_tiles, TS_Q_BLK)),
                 jnp.asarray(buf_lo.reshape(n_tiles, TS_Q_BLK)),
-                interpret=interpret,
+                interpret=interpret_mode(),
             )
         ).reshape(-1)
         res = sel[ok]
@@ -317,11 +316,10 @@ def bmat_rank(keys, fences, queries, fanout: int):
 
 
 def gmm_estep(x, weights, means, stds):
-    interpret = not on_tpu()
     x32 = x.astype(jnp.float32)
     w32 = weights.astype(jnp.float32)
     m32 = means.astype(jnp.float32)
     s32 = stds.astype(jnp.float32)
     x32, n = _pad_to(x32, GMM_N_BLK, 0.0)
-    out = gmm_estep_pallas(x32, w32, m32, s32, interpret=interpret)
+    out = gmm_estep_pallas(x32, w32, m32, s32, interpret=interpret_mode())
     return out[:n]

@@ -31,7 +31,7 @@ def _kernel(tile_hi_ref, tile_lo_ref, q_hi_ref, q_lo_ref, out_ref):
 
 
 def tile_search_pallas(
-    tiles_hi, tiles_lo, q_hi, q_lo, *, interpret: bool = True
+    tiles_hi, tiles_lo, q_hi, q_lo, *, interpret: bool
 ):
     """tiles_*: (n_tiles, TILE) slot keys; q_*: (n_tiles, Q_BLK) routed
     queries. Returns (n_tiles, Q_BLK) local indices (-1 if q below tile)."""

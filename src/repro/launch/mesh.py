@@ -20,16 +20,10 @@ import jax
 
 
 def _mesh(shape, axes):
-    """jax.make_mesh across API generations: ``axis_types`` (and the
-    ``jax.sharding.AxisType`` enum backing it) only exist in newer JAX; every
-    axis here is Auto, which is also the legacy default, so omitting the
-    argument on older versions builds the identical mesh."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -65,8 +65,8 @@ class PrefixCacheIndex:
         n_seed = max(8, 2 * n_shards)
         seed_keys = np.linspace(1, _MASK, n_seed).astype(np.int64)
         per_shard_buf = max(256, self.capacity_hint // max(n_shards, 1))
-        # locate="auto" puts the match()/admit() hot path on the fused
-        # Pallas locate/rank kernels when serving runs on TPU
+        # locate="fused" puts the match()/admit() hot path on the fused
+        # Pallas locate/rank kernels; "auto" runs the jnp spline path
         self.index = ShardedUpLIF(
             seed_keys,
             np.full(n_seed, -1, dtype=np.int64),

@@ -60,17 +60,19 @@ ACTION_NAMES = ("keep", "retrain_shard", "switch_bmat", "split_shard",
                 "merge_shards", "switch_locate")
 
 
-def locate_candidates() -> Tuple[str, ...]:
-    """Strategies the controller may pin a shard to. Off TPU the fused
-    kernels only run in interpret mode — a correctness proxy orders of
-    magnitude slower than the jnp paths — so fused is only a candidate
-    where it is a real kernel. The dispatch axis itself (mixed per-shard
-    strategies in one wave) is exercised either way."""
+def locate_candidates(options: Tuple[str, ...]) -> Tuple[str, ...]:
+    """Strategies the controller may pin a shard to, out of the ones the
+    router can run (``TelemetrySnapshot.locate_options``). Off TPU the
+    fused kernels only run in interpret mode — a correctness proxy orders
+    of magnitude slower than the jnp paths — so fused is only a candidate
+    where it compiles for the chip. The dispatch axis itself (mixed
+    per-shard strategies in one wave) is exercised either way."""
     from repro.kernels.ops import on_tpu
 
     if on_tpu():
-        return LOCATE_STRATEGIES
-    return tuple(s for s in LOCATE_STRATEGIES if s != LOCATE_FUSED)
+        return tuple(options)
+    return tuple(s for s in options if s != LOCATE_FUSED)
+
 
 # state discretization edges
 _FILL_EDGES = np.array([0.05, 0.2, 0.5, 0.8])
@@ -172,7 +174,7 @@ class ShardTuningController:
         ≥10% predicted win — hysteresis against EWMA noise flapping the
         jit-variant set."""
         cur = snap.locate_strategy[s]
-        cands = locate_candidates()
+        cands = locate_candidates(snap.locate_options or LOCATE_STRATEGIES)
         obs = {c: snap.locate_lat.get((s, c)) for c in cands}
         observed = [v for v in obs.values() if v is not None]
         if not observed:

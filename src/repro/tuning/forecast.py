@@ -45,7 +45,8 @@ class ForecastConfig:
     min_obs: int = 256        # observations before the forecast is trusted
     max_batch: int = 8192     # subsample cap per observed batch
     # dense E-step via the Pallas kernel; None = auto (TPU only — interpret
-    # mode on CPU is a python-loop emulation, far slower than jitted jnp)
+    # mode on CPU is an emulation, far slower than numpy). A kernel that
+    # fails raises: nothing downgrades to the host path behind the caller.
     use_pallas: Optional[bool] = None
     seed: int = 0
 
@@ -87,23 +88,19 @@ class UpdateForecaster:
     def _responsibilities(self, x: np.ndarray) -> np.ndarray:
         """(N, K) responsibilities under the current mixture."""
         if self.cfg.use_pallas:
-            try:
-                from repro.kernels.ops import gmm_estep
+            from repro.kernels.ops import gmm_estep
 
-                # unit-domain scaling keeps the f32 kernel conditioned on
-                # 52-bit keys; the shared -log(span) shifts every component
-                # equally and cancels in the softmax
-                xs = jnp.asarray((x - self.lo) / self.span)
-                ms = (self.gmm.means - self.lo) / self.span
-                ss = jnp.maximum(self.gmm.stds / self.span, _MIN_STD_FRAC)
-                return np.asarray(
-                    gmm_estep(xs, self.gmm.weights, ms, ss), dtype=np.float64
-                )
-            except Exception:
-                # missing/incompatible Pallas lowering: degrade, don't die
-                self.cfg.use_pallas = False
-        # host fallback: a K-component E-step over numpy is microseconds
-        # per batch and — unlike a jitted path — indifferent to the batch
+            # unit-domain scaling keeps the f32 kernel conditioned on
+            # 52-bit keys; the shared -log(span) shifts every component
+            # equally and cancels in the softmax
+            xs = jnp.asarray((x - self.lo) / self.span)
+            ms = (self.gmm.means - self.lo) / self.span
+            ss = jnp.maximum(self.gmm.stds / self.span, _MIN_STD_FRAC)
+            return np.asarray(
+                gmm_estep(xs, self.gmm.weights, ms, ss), dtype=np.float64
+            )
+        # host path: a K-component E-step over numpy is microseconds per
+        # batch and — unlike a jitted path — indifferent to the batch
         # length, so the per-wave observe never compiles anything
         w = np.asarray(self.gmm.weights)
         mu = np.asarray(self.gmm.means)

@@ -82,6 +82,9 @@ class TelemetrySnapshot:
     locate_lat: Dict[Tuple[int, str], float] = dataclasses.field(
         default_factory=dict
     )
+    # strategies the router can run at its current shapes (fused only
+    # where the fused kernels' guard admits them)
+    locate_options: Tuple[str, ...] = ()
 
     def shard_measures(self, s: int) -> dict:
         """Section 4.1 measure dict for shard ``s`` (controller state input)."""
@@ -213,4 +216,5 @@ class Telemetry:
             range_lat_ewma=self.range_lat_ewma,
             locate_strategy=index.shard_locate(),
             locate_lat=dict(self.locate_lat),
+            locate_options=index.locate_options(),
         )

@@ -179,8 +179,14 @@ def test_fused_locate_kernel_vs_fops(n_shards):
         j0, c0 = fops._locate(st_sp, idx.slots.keys, idx.rs_model, jq)
         j1, c1 = fops._locate(st_fu, idx.slots.keys, idx.rs_model, jq)
     else:
+        # radix_bits=14 keeps the stacked radix tables inside the fused
+        # guard (S * n_table <= MAX_VMEM_KEYS), so the stacked kernel runs
         idx = ShardedUpLIF(
-            keys, keys + 1, UpLIFConfig(locate="spline"), n_shards=n_shards
+            keys, keys + 1, UpLIFConfig(locate="spline", radix_bits=14),
+            n_shards=n_shards,
+        )
+        assert fops.fused_fits(
+            idx.state.slots.keys, idx.state.model, idx.state.bmat
         )
         st_sp = idx._static()
         st_fu = st_sp._replace(locate="fused")

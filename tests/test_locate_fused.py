@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 import repro.core  # noqa: F401 — x64
+import jax.numpy as jnp
 from repro.core import ShardedUpLIF, UpLIF
 from repro.core.uplif import UpLIFConfig
 from repro.kernels import ops as kops
@@ -115,18 +116,22 @@ def test_single_shard_strategy_equivalence(seed, kind):
 def test_stacked_strategy_equivalence(seed, n_shards):
     """Sharded router: the fused kernels take per-query shard base offsets,
     so S shards run in one launch — results must still match the jnp
-    strategies everywhere, INCLUDING on shard-boundary keys."""
+    strategies everywhere, INCLUDING on shard-boundary keys. radix_bits=14
+    keeps the stacked radix tables inside the fused guard
+    (S * n_table <= MAX_VMEM_KEYS), so the stacked kernels really run."""
     base, vals, ops_tape, probes, ranges = _tape(seed)
     results = {}
     for strat in STRATEGIES:
-        cfg = UpLIFConfig(locate=strat, batch_bucket=256)
+        cfg = UpLIFConfig(locate=strat, batch_bucket=256, radix_bits=14)
         idx = ShardedUpLIF(base, vals, cfg, n_shards=n_shards)
+        assert idx.shard_locate() == (strat,) * n_shards
         # boundary queries: the first key of each shard and its neighbors
         # exercise the sid routing + per-query offset arithmetic edges
         b = idx.boundaries.astype(np.int64)
         probes_b = np.concatenate([probes, b, b - 1, b + 1])
         results[strat] = _run_tape(idx, ops_tape, probes_b, ranges)
         results[strat].append(("size", np.asarray([idx.size])))
+        assert idx.shard_locate() == (strat,) * n_shards
     for strat in ("binsearch", "fused"):
         _assert_identical(strat, results["spline"], results[strat])
 
@@ -144,21 +149,25 @@ def test_mixed_per_shard_strategy_equivalence(seed, assignment):
     the wave across at most three launches, each query taking (j, ins_cap)
     from its own shard's branch. Every visible result must match the
     uniform-strategy router on the same tape, shard-boundary keys and
-    mid-tape strategy flips included."""
+    mid-tape strategy flips included. radix_bits=14 keeps the fused guard
+    open at S=3, so the fused shards run the kernels."""
     base, vals, ops_tape, probes, ranges = _tape(seed)
-    cfg = UpLIFConfig(locate="spline", batch_bucket=256)
+    cfg = UpLIFConfig(locate="spline", batch_bucket=256, radix_bits=14)
     ref = ShardedUpLIF(base, vals, cfg, n_shards=3)
     mixed = ShardedUpLIF(base, vals, cfg, n_shards=3)
     for s, strat in enumerate(assignment):
         mixed.set_shard_locate(s, strat)
+    assert mixed.shard_locate() == assignment
     b = ref.boundaries.astype(np.int64)
     probes_b = np.concatenate([probes, b, b - 1, b + 1])
     r_ref = _run_tape(ref, ops_tape, probes_b, ranges)
     r_mix = _run_tape(mixed, ops_tape, probes_b, ranges)
     _assert_identical(f"mixed{assignment}", r_ref, r_mix)
     assert ref.size == mixed.size
+    assert mixed.shard_locate() == assignment
     # a controller flip mid-stream must not disturb state or results
     mixed.set_shard_locate(1, "fused")
+    assert mixed.shard_locate()[1] == "fused"
     fa, va = ref.lookup(probes_b)
     fb, vb = mixed.lookup(probes_b)
     np.testing.assert_array_equal(fa, fb)
@@ -249,14 +258,31 @@ def test_small_shift_prefix_saturates():
 
 
 def test_fused_guard_falls_back_cleanly():
-    """Shapes outside the VMEM/precision guards must fall through to the
-    jnp spline path with identical results (the guard is static, so this
-    just pins that both sides of the branch agree)."""
+    """Shapes outside the VMEM/precision guards resolve to the jnp spline
+    path, and the router reports the strategy that runs: every shard of a
+    router whose stacked radix tables exceed the guard (S = 2 at the
+    default 2**16 buckets) says spline, and fops refuses a fused static at
+    those shapes instead of silently running something else."""
+    from repro.core import fops
+
     assert not kops.locate_fusable(kops.MAX_F32_POSITIONS + 1, 64, 64, 1)
     assert not kops.locate_fusable(1024, 64, 64,
                                    kops.MAX_VMEM_SLOTS // 1024 + 1)
     assert kops.locate_fusable(1024, 64, 64, 1)
     assert not kops.rank_fusable(kops.MAX_VMEM_KEYS + 1, 64)
+
+    keys = make_keys(900, 5, hi=KEY_HI)
+    idx = ShardedUpLIF(keys, keys + 1, UpLIFConfig(locate="fused"),
+                       n_shards=2)
+    assert idx.shard_locate() == ("spline", "spline")
+    assert "fused" not in idx.locate_options()
+    assert not idx.set_shard_locate(1, "fused")
+    f, v = idx.lookup(keys[:50])
+    assert f.all() and np.array_equal(v, keys[:50] + 1)
+    st = idx.state
+    with pytest.raises(ValueError, match="fused"):
+        fops.slookup(st, jnp.asarray(keys[:256]), idx._jbounds,
+                     static=idx._static()._replace(locate="fused"))
 
 
 def test_auto_resolution():
@@ -266,11 +292,16 @@ def test_auto_resolution():
         resolve_locate,
     )
 
-    assert resolve_locate("auto", on_tpu=True) == LOCATE_FUSED
-    assert resolve_locate("auto", on_tpu=False) == LOCATE_SPLINE
-    assert resolve_locate("fused", on_tpu=False) == LOCATE_FUSED
+    # auto is spline on every platform; fused is an explicit choice
+    assert resolve_locate("auto") == LOCATE_SPLINE
+    assert resolve_locate("fused") == LOCATE_FUSED
+    # the shape guard wins over the request: fused never runs where the
+    # kernels refuse the shapes
+    assert resolve_locate("auto", fits=False) == LOCATE_SPLINE
+    assert resolve_locate("fused", fits=False) == LOCATE_SPLINE
+    assert resolve_locate("binsearch", fits=False) == "binsearch"
     with pytest.raises(ValueError):
-        resolve_locate("nope", on_tpu=False)
+        resolve_locate("nope")
     # config validation rejects unknown strategies up front
     with pytest.raises(AssertionError):
         UpLIFConfig(locate="nope")
