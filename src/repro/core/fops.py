@@ -60,6 +60,7 @@ _I64_MAX = np.iinfo(np.int64).max
 class InsertResult(NamedTuple):
     pending: jnp.ndarray     # bool[n] — keys still unplaced after the rounds
     n_overflow: jnp.ndarray  # int64 — count routed to the BMAT this call
+    n_round_keys: jnp.ndarray  # int64 — keys pending when the rounds start
 
 
 class RangeResult(NamedTuple):
@@ -503,6 +504,7 @@ def insert(
         )
         bmat = bmat._replace(vals=bvals)
         pending = pending & ~upd
+    n_round_keys = jnp.sum(pending, dtype=jnp.int64)
 
     rounds = max(1, static.insert_rounds)
 
@@ -578,7 +580,9 @@ def insert(
         counters=counters,
         halves=halves,
     )
-    return new_state, InsertResult(pending=pending, n_overflow=n_over)
+    return new_state, InsertResult(
+        pending=pending, n_overflow=n_over, n_round_keys=n_round_keys
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -981,15 +985,18 @@ def slookup(state: UpLIFState, q, boundaries, codes=None, *,
     ``codes`` is the per-shard strategy index (None unless ``static.locate``
     is a mixed tuple — see ``_locate_stacked``)."""
     sid = _route_on_device(boundaries, q)
-    j, _ = _locate_stacked(
-        static, state.slots.keys, state.model, q, sid,
-        halves=state.halves, codes=codes,
-    )
-    _, alive, vals, _ = _probe_stacked(state.slots, j, q, sid)
-    ranks = _bmat_rank_stacked(
-        static, state.bmat, q, sid, halves=state.halves, codes=codes
-    )
-    _, b_alive, b_vals, _ = _bmat_probe_stacked(state.bmat, ranks, q, sid)
+    # named phases group the device operations in a profiler trace
+    with jax.named_scope("locate"):
+        j, _ = _locate_stacked(
+            static, state.slots.keys, state.model, q, sid,
+            halves=state.halves, codes=codes,
+        )
+        _, alive, vals, _ = _probe_stacked(state.slots, j, q, sid)
+    with jax.named_scope("rank"):
+        ranks = _bmat_rank_stacked(
+            static, state.bmat, q, sid, halves=state.halves, codes=codes
+        )
+        _, b_alive, b_vals, _ = _bmat_probe_stacked(state.bmat, ranks, q, sid)
     b_alive = b_alive & ~alive
     return alive | b_alive, jnp.where(b_alive, b_vals, vals)
 
@@ -1180,27 +1187,30 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
         return qk, j, icap
 
     # ---- upsert keys already in the slot array or live in the BMAT ------
-    qk, j, icap = locate(sk, slot_halves, pending)
-    slots2 = SlotsState(
-        keys=sk.reshape(S, cap), vals=sv.reshape(S, cap),
-        occ=so.reshape(S, cap),
-    )
-    hit, alive, _, jj = _probe_stacked(slots2, j, qk, sid)
-    n_keys = n_keys + _seg_add(S, sid, hit & ~alive)
-    sv = sv.at[jnp.where(hit, sid * cap + jj, S * cap + 1)].set(
-        vals, mode="drop"
-    )
-    ranks = _bmat_rank_stacked(
-        static, bmat, qk, sid, halves=halves, codes=codes
-    )
-    _, b_alive, _, bidx = _bmat_probe_stacked(bmat, ranks, qk, sid)
-    upd = b_alive & pending
-    bcap = bmat.keys.shape[1]
-    bvals = bmat.vals.reshape(-1).at[
-        jnp.where(upd, sid * bcap + bidx, S * bcap + 1)
-    ].set(vals, mode="drop").reshape(S, bcap)
-    bmat = bmat._replace(vals=bvals)
-    pending = pending & ~hit & ~upd
+    # (named phases group the device operations in a profiler trace)
+    with jax.named_scope("probe"):
+        qk, j, icap = locate(sk, slot_halves, pending)
+        slots2 = SlotsState(
+            keys=sk.reshape(S, cap), vals=sv.reshape(S, cap),
+            occ=so.reshape(S, cap),
+        )
+        hit, alive, _, jj = _probe_stacked(slots2, j, qk, sid)
+        n_keys = n_keys + _seg_add(S, sid, hit & ~alive)
+        sv = sv.at[jnp.where(hit, sid * cap + jj, S * cap + 1)].set(
+            vals, mode="drop"
+        )
+        ranks = _bmat_rank_stacked(
+            static, bmat, qk, sid, halves=halves, codes=codes
+        )
+        _, b_alive, _, bidx = _bmat_probe_stacked(bmat, ranks, qk, sid)
+        upd = b_alive & pending
+        bcap = bmat.keys.shape[1]
+        bvals = bmat.vals.reshape(-1).at[
+            jnp.where(upd, sid * bcap + bidx, S * bcap + 1)
+        ].set(vals, mode="drop").reshape(S, bcap)
+        bmat = bmat._replace(vals=bvals)
+        pending = pending & ~hit & ~upd
+    n_round_keys = jnp.sum(pending, dtype=jnp.int64)
 
     rounds = max(1, static.insert_rounds)
 
@@ -1240,23 +1250,25 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
         return (sk, sv, so, slot_halves, pending, n_keys + ok_per,
                 n_inplace + ok_per, jnp.minimum(min_gran, span_per), j, icap)
 
-    sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran, _, _ = (
-        jax.lax.fori_loop(
-            0, rounds, accept_round,
-            (sk, sv, so, slot_halves, pending, n_keys, c.n_inplace,
-             c.min_granularity, j, icap),
+    with jax.named_scope("rounds"):
+        sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran, _, _ = (
+            jax.lax.fori_loop(
+                0, rounds, accept_round,
+                (sk, sv, so, slot_halves, pending, n_keys, c.n_inplace,
+                 c.min_granularity, j, icap),
+            )
         )
-    )
 
     if halves is not None:
         halves = halves._replace(
             slot_hi=slot_halves[0].reshape(S, cap),
             slot_lo=slot_halves[1].reshape(S, cap),
         )
-    bmat, n_bmat_live, n_over, bh = _merge_pending_stacked(
-        static, bmat, keys, vals, pending, sid, n_bmat_live,
-        halves=halves, codes=codes,
-    )
+    with jax.named_scope("merge"):
+        bmat, n_bmat_live, n_over, bh = _merge_pending_stacked(
+            static, bmat, keys, vals, pending, sid, n_bmat_live,
+            halves=halves, codes=codes,
+        )
     if halves is not None:
         halves = halves._replace(
             bmat_hi=bh[0], bmat_lo=bh[1], fence_hi=bh[2], fence_lo=bh[3]
@@ -1279,7 +1291,8 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
         halves=halves,
     )
     return new_state, InsertResult(
-        pending=pending, n_overflow=jnp.sum(n_over)
+        pending=pending, n_overflow=jnp.sum(n_over),
+        n_round_keys=n_round_keys,
     )
 
 

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.types import GMMState
 
 _SQRT2 = float(np.sqrt(2.0))
@@ -110,9 +111,9 @@ def gmm_cdf_np(state: GMMState, x: np.ndarray) -> np.ndarray:
     from scipy.special import erf  # scipy ships with jax
 
     x = np.asarray(x, dtype=np.float64)
-    w = np.asarray(state.weights)
-    mu = np.asarray(state.means)
-    sd = np.asarray(state.stds)
+    w = obs.fetch("gmm.cdf", state.weights)
+    mu = obs.fetch("gmm.cdf", state.means)
+    sd = obs.fetch("gmm.cdf", state.stds)
     z = (x[:, None] - mu[None, :]) / (sd[None, :] * _SQRT2)
     return (w[None, :] * 0.5 * (1.0 + erf(z))).sum(axis=1)
 

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import fops
 from repro.core.bmat import BMAT, BPMAT, RBMAT, _make_fences, bmat_height
 from repro.core.shapes import grow_capacity, pow2_at_least
@@ -748,15 +749,21 @@ class ShardedUpLIF:
         return jnp.asarray(q), n, *outs
 
     # -- queries ---------------------------------------------------------------
+    @obs.traced("router.lookup")
     def lookup(
         self, queries: np.ndarray, pad_to: Optional[int] = None
     ) -> Tuple[np.ndarray, np.ndarray]:
-        queries = np.asarray(queries, dtype=np.int64)
-        q, n = self._pad_route(queries, width=pad_to)
-        state, boundaries, jb, codes, static = self._read_view()
+        with obs.span("router.prepare"):
+            queries = np.asarray(queries, dtype=np.int64)
+            q, n = self._pad_route(queries, width=pad_to)
+        with obs.span("router.view"):
+            state, boundaries, jb, codes, static = self._read_view()
         t0 = time.perf_counter()
-        f, v = fops.slookup(state, q, jb, codes, static=static)
-        f, v = np.asarray(f), np.asarray(v)  # sync: time the whole dispatch
+        with obs.span("router.launch"):
+            f, v = fops.slookup(state, q, jb, codes, static=static)
+        with obs.span("router.wait"):  # sync: time the whole dispatch
+            f = obs.fetch("router.lookup", f)
+            v = obs.fetch("router.lookup", v)
         dt = time.perf_counter() - t0
         self.n_lookups += n
         if n:
@@ -788,45 +795,62 @@ class ShardedUpLIF:
                 (kind, keys[m], vals[m] if vals is not None else None)
             )
 
+    @obs.traced("router.insert")
     def insert(
         self,
         keys: np.ndarray,
         vals: Optional[np.ndarray] = None,
         pad_to: Optional[int] = None,
     ) -> int:
-        keys = np.asarray(keys, dtype=np.int64)
-        if vals is None:
-            vals = keys.copy()
-        vals = np.asarray(vals, dtype=np.int64)
-        if len(keys) == 0:
-            return 0
-        if self._logs:
-            self._log_op("insert", keys, vals)
-        self._observe_updates(keys)
-        q, n, vm = self._pad_route(keys, vals, width=pad_to)
-        self._ensure_bmat_capacity(int(q.shape[0]))
-        state, res = fops.sinsert(
-            self.state, q, vm, self._jbounds, self._jcodes,
-            static=self._static(),
-        )
+        """Upsert a batch; returns how many keys went to the BMAT. Counts
+        the batch's keys (``sinsert.keys``) and those still pending when
+        the accept rounds start (``sinsert.round_keys``)."""
+        with obs.span("router.prepare"):
+            keys = np.asarray(keys, dtype=np.int64)
+            if vals is None:
+                vals = keys.copy()
+            vals = np.asarray(vals, dtype=np.int64)
+            if len(keys) == 0:
+                return 0
+            if self._logs:
+                self._log_op("insert", keys, vals)
+            self._observe_updates(keys)
+            q, n, vm = self._pad_route(keys, vals, width=pad_to)
+        with obs.span("router.capacity"):
+            self._ensure_bmat_capacity(int(q.shape[0]))
+        with obs.span("router.launch"):
+            state, res = fops.sinsert(
+                self.state, q, vm, self._jbounds, self._jcodes,
+                static=self._static(),
+            )
         with self._lock:
             self.state = state
-        return int(res.n_overflow)
+        with obs.span("router.wait"):
+            n_over, n_round = obs.fetch(
+                "router.insert", (res.n_overflow, res.n_round_keys)
+            )
+        obs.count("sinsert.keys", n)
+        obs.count("sinsert.round_keys", int(n_round))
+        return int(n_over)
 
+    @obs.traced("router.delete")
     def delete(
         self, keys: np.ndarray, pad_to: Optional[int] = None
     ) -> np.ndarray:
-        keys = np.asarray(keys, dtype=np.int64)
-        if self._logs:
-            self._log_op("delete", keys, None)
-        q, n = self._pad_route(keys, width=pad_to)
-        state, hit = fops.sdelete(
-            self.state, q, self._jbounds, self._jcodes,
-            static=self._static(),
-        )
+        with obs.span("router.prepare"):
+            keys = np.asarray(keys, dtype=np.int64)
+            if self._logs:
+                self._log_op("delete", keys, None)
+            q, n = self._pad_route(keys, width=pad_to)
+        with obs.span("router.launch"):
+            state, hit = fops.sdelete(
+                self.state, q, self._jbounds, self._jcodes,
+                static=self._static(),
+            )
         with self._lock:
             self.state = state
-        return np.asarray(hit)[:n]
+        with obs.span("router.wait"):
+            return obs.fetch("router.delete", hit)[:n]
 
     def range_query(self, lo: int, hi: int, max_out: int = 1024):
         ks, vs = self.range_query_batch(
@@ -836,6 +860,7 @@ class ShardedUpLIF:
         )
         return ks[0], vs[0]
 
+    @obs.traced("router.range")
     def range_query_batch(
         self, lo: np.ndarray, hi: np.ndarray, max_out: int = 1024
     ):
@@ -846,34 +871,37 @@ class ShardedUpLIF:
         lo = np.asarray(lo, dtype=np.int64)
         hi = np.asarray(hi, dtype=np.int64)
         n = len(lo)
-        with self._lock:
+        with obs.span("router.view"), self._lock:
             state, boundaries = self.state, self.boundaries
             static = self._static()
             per_shard = tuple(self._locate_per_shard)
-        n_shards = len(boundaries) + 1
-        # range scans unroll per shard, so mixed dispatch is just each
-        # shard's scan compiled under its own (uniform) strategy
-        statics = tuple(
-            static._replace(locate=per_shard[s]) for s in range(n_shards)
-        )
-        edges = np.concatenate([[0], boundaries, [KEY_MAX]])
-        picks = [
-            np.nonzero((hi >= edges[s]) & (lo < edges[s + 1]))[0]
-            for s in range(n_shards)
-        ]
-        B = self._bucket(max(max((len(p) for p in picks), default=1), 1))
-        lo_m = np.full((n_shards, B), KEY_MAX, dtype=np.int64)
-        hi_m = np.zeros((n_shards, B), dtype=np.int64)
-        for s, p in enumerate(picks):
-            lo_m[s, : len(p)] = lo[p]
-            hi_m[s, : len(p)] = hi[p]
-        res = _vrange(
-            state, jnp.asarray(lo_m), jnp.asarray(hi_m),
-            statics=statics, max_out=max_out,
-        )
-        ks = np.asarray(res.keys)
-        vs = np.asarray(res.vals)
-        cn = np.asarray(res.count)
+        with obs.span("router.prepare"):
+            n_shards = len(boundaries) + 1
+            # range scans unroll per shard, so mixed dispatch is just each
+            # shard's scan compiled under its own (uniform) strategy
+            statics = tuple(
+                static._replace(locate=per_shard[s]) for s in range(n_shards)
+            )
+            edges = np.concatenate([[0], boundaries, [KEY_MAX]])
+            picks = [
+                np.nonzero((hi >= edges[s]) & (lo < edges[s + 1]))[0]
+                for s in range(n_shards)
+            ]
+            B = self._bucket(max(max((len(p) for p in picks), default=1), 1))
+            lo_m = np.full((n_shards, B), KEY_MAX, dtype=np.int64)
+            hi_m = np.zeros((n_shards, B), dtype=np.int64)
+            for s, p in enumerate(picks):
+                lo_m[s, : len(p)] = lo[p]
+                hi_m[s, : len(p)] = hi[p]
+            lo_m, hi_m = jnp.asarray(lo_m), jnp.asarray(hi_m)
+        with obs.span("router.launch"):
+            res = _vrange(
+                state, lo_m, hi_m, statics=statics, max_out=max_out,
+            )
+        with obs.span("router.wait"):
+            ks = obs.fetch("router.range", res.keys)
+            vs = obs.fetch("router.range", res.vals)
+            cn = obs.fetch("router.range", res.count)
         parts_k: List[List[np.ndarray]] = [[] for _ in range(n)]
         parts_v: List[List[np.ndarray]] = [[] for _ in range(n)]
         for s, p in enumerate(picks):
@@ -891,6 +919,7 @@ class ShardedUpLIF:
                 out_v.append(np.zeros(0, dtype=np.int64))
         return out_k, out_v
 
+    @obs.traced("router.apply_wave")
     def apply_wave(self, wave: MixedWave) -> MixedWaveResult:
         """Dispatch one mixed-op wave (the gateway's flush unit).
 
@@ -938,7 +967,7 @@ class ShardedUpLIF:
 
     # -- capacity management ---------------------------------------------------
     def _ensure_bmat_capacity(self, incoming: int):
-        sizes = np.asarray(self.state.bmat.size)
+        sizes = obs.fetch("router.capacity", self.state.bmat.size)
         bcap = int(self.state.bmat.keys.shape[1])
         need = int(sizes.max()) + incoming
         if need <= bcap - 1:

@@ -36,6 +36,7 @@ the wave loop every bench already runs.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -43,6 +44,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.shapes import padded_width, pow2_at_least
 from repro.core.sharded import MixedWave, ShardedUpLIF
 from repro.core.types import KEY_MAX
@@ -111,7 +113,6 @@ class RequestGateway:
         self.n_rejected = 0
         self.flush_triggers = {"size": 0, "deadline": 0, "close": 0}
         self.pad_widths: Dict[str, Dict[int, int]] = {op: {} for op in OPS}
-        self.pressure_events: List[tuple] = []   # (t, level)
         self.first_reject_t: Optional[float] = None
         self.last_error: Optional[str] = None
         self._thread = threading.Thread(
@@ -177,7 +178,7 @@ class RequestGateway:
         if lvl == self._pressure:
             return
         self._pressure = lvl
-        self.pressure_events.append((time.perf_counter(), lvl))
+        obs.count("gateway.pressure_events")
         if self.tuner is not None:
             self.tuner.set_pressure(lvl)
 
@@ -266,20 +267,21 @@ class RequestGateway:
                     fu.set_exception(e)
             return
         dt = time.perf_counter() - t0
-        for i, fu in enumerate(futs["insert"]):
-            fu.set_result(True)
-        for i, fu in enumerate(futs["delete"]):
-            fu.set_result(bool(res.delete_hit[i]))
-        for i, fu in enumerate(futs["lookup"]):
-            fu.set_result(
-                (bool(res.lookup_found[i]), int(res.lookup_vals[i]))
-            )
-        for i, fu in enumerate(futs["range"]):
-            fu.set_result((res.range_keys[i], res.range_vals[i]))
-        if self.cfg.on_complete is not None:
-            for fs in futs.values():
-                for fu in fs:
-                    self.cfg.on_complete(fu)
+        with obs.span("gateway.complete"):
+            for i, fu in enumerate(futs["insert"]):
+                fu.set_result(True)
+            for i, fu in enumerate(futs["delete"]):
+                fu.set_result(bool(res.delete_hit[i]))
+            for i, fu in enumerate(futs["lookup"]):
+                fu.set_result(
+                    (bool(res.lookup_found[i]), int(res.lookup_vals[i]))
+                )
+            for i, fu in enumerate(futs["range"]):
+                fu.set_result((res.range_keys[i], res.range_vals[i]))
+            if self.cfg.on_complete is not None:
+                for fs in futs.values():
+                    for fu in fs:
+                        self.cfg.on_complete(fu)
         self.n_waves += 1
         self.n_ops += n
         if dt > 0 and n > 0:
@@ -295,19 +297,26 @@ class RequestGateway:
 
     def _run(self):
         while True:
-            with self._cond:
-                now = time.perf_counter()
-                trigger = self._due_trigger(now)
-                while not self._closed and trigger is None:
-                    self._cond.wait(self._wait_timeout(now))
+            # the wave's span opens once a trigger fires: the flusher's
+            # waits for requests lie outside every span
+            with contextlib.ExitStack() as wave_span:
+                with self._cond:
                     now = time.perf_counter()
                     trigger = self._due_trigger(now)
-                if self._closed:
-                    if self._backlog == 0:
-                        return
-                    trigger = "close"  # final drain: flush whatever is left
-                wave, futs = self._drain_wave(trigger)
-            self._dispatch(wave, futs)
+                    while not self._closed and trigger is None:
+                        self._cond.wait(self._wait_timeout(now))
+                        now = time.perf_counter()
+                        trigger = self._due_trigger(now)
+                    if self._closed:
+                        if self._backlog == 0:
+                            return
+                        trigger = "close"  # final drain: flush what is left
+                    wave_span.enter_context(
+                        obs.span("gateway.wave", wave=obs.RECORDER.new_wave())
+                    )
+                    with obs.span("gateway.drain"):
+                        wave, futs = self._drain_wave(trigger)
+                self._dispatch(wave, futs)
 
     # -- warmup ----------------------------------------------------------------
     def warmup(self) -> Dict[str, List[int]]:
@@ -417,7 +426,7 @@ class RequestGateway:
             "backlog": self._backlog,
             "rejected": self.n_rejected,
             "pressure": self._pressure,
-            "pressure_events": len(self.pressure_events),
+            "pressure_events": obs.RECORDER.counter("gateway.pressure_events"),
             "flush_triggers": dict(self.flush_triggers),
             "pad_widths": {
                 op: dict(sorted(w.items()))
@@ -426,4 +435,5 @@ class RequestGateway:
             "drain_rate_ops_s": self._rate_ewma,
             "closed": self._closed,
             "last_error": self.last_error,
+            "obs": obs.RECORDER.snapshot(),
         }

@@ -38,6 +38,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.sharded import RouterSnapshot, ShardedUpLIF, StateDelta  # noqa: F401
 from repro.core.types import KEY_MAX
 from repro.tuning.controller import (  # noqa: F401
@@ -150,10 +151,12 @@ class SelfTuner:
         return self
 
     # -- the calls serving code makes -----------------------------------------
+    @obs.traced("tuner.observe_inserts")
     def observe_inserts(self, keys: np.ndarray):
         """Feed observed insert keys to the D_update forecaster."""
         if self.forecaster is not None and len(keys):
-            self.forecaster.observe(keys)
+            with obs.span("tuner.forecast"):
+                self.forecaster.observe(keys)
             self.scheduler.observe_inserts(len(keys))
             self._wave_inserts += len(keys)
 
@@ -167,6 +170,7 @@ class SelfTuner:
         if self.scheduler is not None:
             self.scheduler.set_pressure(level)
 
+    @obs.traced("tuner.after_wave")
     def after_wave(self, n_ops: int, seconds: float) -> Optional[dict]:
         """Report a finished request wave; maybe plan one maintenance step."""
         if self.scheduler is None or self.index is None:

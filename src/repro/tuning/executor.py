@@ -33,6 +33,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.sharded import (
     RouterSnapshot,
     StateDelta,
@@ -162,10 +163,11 @@ class MaintenanceExecutor:
                 continue
             if item is None:
                 break
-            plan, snapshot = item
+            plan, snapshot, wave = item
             t0 = time.perf_counter()
             try:
-                delta = build(plan, snapshot)
+                with obs.span("executor.build", wave=wave, arg=plan.build_id):
+                    delta = build(plan, snapshot)
                 err = None
             except Exception as e:  # surface on the serving thread
                 delta, err = None, e
@@ -203,7 +205,7 @@ class MaintenanceExecutor:
         submit a build overlapping an in-flight build's key interval."""
         self._ensure_threads()
         self._inflight += 1
-        self._in.put((plan, snapshot))
+        self._in.put((plan, snapshot, obs.RECORDER.current_wave()))
 
     def poll(self) -> List[BuildResult]:
         """All builds finished since the last poll (non-blocking)."""

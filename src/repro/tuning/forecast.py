@@ -31,6 +31,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.gmm import gmm_cdf_np, init_gmm_uniform
 from repro.core.nullifier import gap_sizes
 from repro.core.types import GMMState
@@ -97,14 +98,16 @@ class UpdateForecaster:
             ms = (self.gmm.means - self.lo) / self.span
             ss = jnp.maximum(self.gmm.stds / self.span, _MIN_STD_FRAC)
             return np.asarray(
-                gmm_estep(xs, self.gmm.weights, ms, ss), dtype=np.float64
+                obs.fetch("forecast.estep",
+                          gmm_estep(xs, self.gmm.weights, ms, ss)),
+                dtype=np.float64,
             )
         # host path: a K-component E-step over numpy is microseconds per
         # batch and — unlike a jitted path — indifferent to the batch
         # length, so the per-wave observe never compiles anything
-        w = np.asarray(self.gmm.weights)
-        mu = np.asarray(self.gmm.means)
-        sd = np.maximum(np.asarray(self.gmm.stds), 1e-300)
+        w = obs.fetch("forecast.gmm", self.gmm.weights)
+        mu = obs.fetch("forecast.gmm", self.gmm.means)
+        sd = np.maximum(obs.fetch("forecast.gmm", self.gmm.stds), 1e-300)
         z = (x[:, None] - mu[None, :]) / sd[None, :]
         logp = np.log(w[None, :]) - 0.5 * z * z - np.log(sd[None, :])
         m = logp.max(axis=1, keepdims=True)
@@ -134,7 +137,7 @@ class UpdateForecaster:
         var = np.maximum(self._s2 / s0 - mu * mu, 0.0)
         std = np.maximum(np.sqrt(var), _MIN_STD_FRAC * self.span)
         drift = float(
-            np.mean(np.abs(mu - np.asarray(self.gmm.means)))
+            np.mean(np.abs(mu - obs.fetch("forecast.gmm", self.gmm.means)))
         ) / self.span
         self.drift_ewma = 0.8 * self.drift_ewma + 0.2 * drift
         self.gmm = GMMState(

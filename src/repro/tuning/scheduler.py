@@ -45,10 +45,11 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.sharded import ShardedUpLIF, intervals_overlap
 from repro.core.types import GMMState
 from repro.tuning.controller import (
@@ -133,7 +134,6 @@ class MaintenanceScheduler:
         self._pending: Optional[Tuple] = None
         self._cost_est: Dict[int, float] = {}
         self.time_in_maintenance = 0.0
-        self.actions_log: List[dict] = []
         # plan/build/commit bookkeeping
         self.executor: Optional[MaintenanceExecutor] = (
             MaintenanceExecutor(config.max_concurrent_builds)
@@ -492,168 +492,176 @@ class MaintenanceScheduler:
 
         t0 = time.perf_counter()
         replayed0 = index.n_replayed_ops
-        committed = self._commit_finished(index)
-        drained = self._advance_drains(index)
+        with obs.span("tuner.commit"):
+            committed = self._commit_finished(index)
+        with obs.span("tuner.drain"):
+            drained = self._advance_drains(index)
 
-        snap = self.telemetry.snapshot(index)
-        heat = (
-            self.forecaster.shard_mass(index.boundaries)
-            if self.forecaster is not None
-            else np.full(index.n_shards, 1.0 / index.n_shards)
-        )
-        s = self.controller.focus_shard(snap, heat)
-        state = self.controller.encode(snap, s, heat)
-        mask = self.controller.action_mask(snap, s)
-
-        # -- capacity guards: EVERY wave, ahead of the learned policy -------
-        # Forecast-driven proactive presize (cheap, not a learned action).
-        # Capacity serves the FORECAST HORIZON only: if the predicted
-        # insert stream wouldn't fit an *empty* buffer, jump once with
-        # margin — every presize changes the BMAT's jit shapes, so land
-        # above the need instead of chasing it in recompile-triggering
-        # increments. Two gates keep it honest: the pressure must be
-        # *predicted* (forecast need beyond capacity) AND *materializing*
-        # (the buffer is actually filling — inserts the gapped array
-        # absorbs in place need no buffer capacity, whatever the forecast
-        # says). Capacity already used is the absorb guard's business,
-        # never a reason to grow further.
-        presized = False
-        bcap = int(index.state.bmat.keys.shape[1])
-        if self.forecaster is not None and self.forecaster.ready:
-            horizon = int(
-                self.cfg.presize_horizon * max(self._insert_ewma, 1.0)
+        snap = self.telemetry.snapshot(index)  # span tuner.telemetry
+        with obs.span("tuner.decide"):
+            heat = (
+                self.forecaster.shard_mass(index.boundaries)
+                if self.forecaster is not None
+                else np.full(index.n_shards, 1.0 / index.n_shards)
             )
-            need = int(
-                self.cfg.presize_margin
-                * self.forecaster.bmat_presize(index.boundaries, horizon)
-            )
-            if need > bcap and int(snap.bmat_size.max()) > bcap // 2:
-                p0 = time.perf_counter()
-                presized = index.presize_bmat(need)
-                bcap = int(index.state.bmat.keys.shape[1])
-                if presized:  # guards are charged as they run (no build)
-                    self._budget = max(
-                        self._budget - (time.perf_counter() - p0), 0.0
-                    )
+            s = self.controller.focus_shard(snap, heat)
+            state = self.controller.encode(snap, s, heat)
+            mask = self.controller.action_mask(snap, s)
 
-        # capacity-debt guard (analogous to LSM compaction-debt limits): a
-        # delta buffer about to overflow its capacity would force an
-        # organic reallocation — new jit shapes, mid-wave — so an absorb
-        # retrain is mandatory no matter what the policy prefers. It
-        # watches the FULLEST buffer, not the (heat-biased) focus shard —
-        # any shard can hit the debt limit. This also keeps learning
-        # safe: the controller explores within bounds the scheduler
-        # enforces. With async builds the forced absorb becomes an urgent
-        # *plan*; while one is already in flight the buffer may organically
-        # grow once, which the monotone shape discipline absorbs.
-        hot = int(np.argmax(snap.bmat_size))
-        forced = (
-            int(snap.bmat_size[hot]) > 0
-            and float(snap.bmat_size[hot])
-            > self.cfg.force_absorb_fill * bcap
-        )
-
-        # close the reward loop for the previous learned action on the
-        # normal cadence (Algorithm 1 lines 13-17) — even when a forced
-        # absorb preempts this wave's choice, so the old action's reward
-        # window doesn't silently stretch over later maintenance stalls
-        if decide and self._pending is not None:
-            p_state, p_action, _ = self._pending
-            r = self.controller.reward(
-                snap.throughput_ewma, snap.memory_ewma,
-                snap.range_lat_ewma,
-            )
-            self.controller.update(p_state, p_action, r, state, mask)
-            self._pending = None
-
-        a, deferred = A_KEEP, False
-        s_apply = s
-        if forced:
-            a, s_apply = A_RETRAIN_SHARD, hot
-        elif decide:
-            a = self.controller.choose(
-                state, mask, explore=self.cfg.explore,
-                snap=snap, s=s, heat=heat,
-            )
-        elif not presized and committed == 0 and drained == 0:
-            return None
-
-        # -- translate the decision into a plan / direct action -------------
-        changed = False
-        if a in BUILD_ACTIONS:
-            if a == A_MERGE_SHARDS:
-                s_apply = self.controller.coldest_pair(snap)
-            if not self._admit(index, a, s_apply, forced):
-                # no free worker slot, interval overlaps an in-flight
-                # build / draining commit, or unaffordable — defer
-                a, deferred = A_KEEP, True
-            else:
-                self.controller.action_counts[a] += 1
-                changed = self._dispatch(
-                    index, self._make_plan(a, s_apply, forced)
+            # -- capacity guards: EVERY wave, ahead of the learned policy ---
+            # Forecast-driven proactive presize (cheap, not a learned action).
+            # Capacity serves the FORECAST HORIZON only: if the predicted
+            # insert stream wouldn't fit an *empty* buffer, jump once with
+            # margin — every presize changes the BMAT's jit shapes, so land
+            # above the need instead of chasing it in recompile-triggering
+            # increments. Two gates keep it honest: the pressure must be
+            # *predicted* (forecast need beyond capacity) AND *materializing*
+            # (the buffer is actually filling — inserts the gapped array
+            # absorbs in place need no buffer capacity, whatever the forecast
+            # says). Capacity already used is the absorb guard's business,
+            # never a reason to grow further.
+            presized = False
+            bcap = int(index.state.bmat.keys.shape[1])
+            if self.forecaster is not None and self.forecaster.ready:
+                horizon = int(
+                    self.cfg.presize_horizon * max(self._insert_ewma, 1.0)
                 )
-        elif a == A_SWITCH_BMAT:
-            if self.pressure >= 1:
-                a, deferred = A_KEEP, True  # shed: no structural changes
-            elif self._inflight or index.active_intervals():
-                # the switch revises the WHOLE keyspace: it would void
-                # every in-flight build and draining commit
-                a, deferred = A_KEEP, True
-            elif self._estimated_cost(a) > self._available():
-                a, deferred = A_KEEP, True
-            else:
-                self.controller.action_counts[a] += 1
-                sw0 = time.perf_counter()  # own timer: t0 covers commits
-                index.switch_bmat_type()
-                self._charge(A_SWITCH_BMAT, time.perf_counter() - sw0)
-                changed = True
-        elif a == A_SWITCH_LOCATE:
-            # metadata-only: no arrays move, results are byte-identical
-            # across strategies, so — unlike switch_bmat — the repin needs
-            # neither an in-flight-build veto nor a revision record; only
-            # overload sheds it (the flipped wave may pay one jit variant)
-            if self.pressure >= 1:
-                a, deferred = A_KEEP, True
-            elif self._estimated_cost(a) > self._available():
-                a, deferred = A_KEEP, True
-            else:
-                pick = self.controller.pick_locate(snap, s)
-                sw0 = time.perf_counter()
-                changed = index.set_shard_locate(s, pick)
-                if changed:
+                need = int(
+                    self.cfg.presize_margin
+                    * self.forecaster.bmat_presize(index.boundaries, horizon)
+                )
+                if need > bcap and int(snap.bmat_size.max()) > bcap // 2:
+                    p0 = time.perf_counter()
+                    with obs.span("tuner.act"):
+                        presized = index.presize_bmat(need)
+                    bcap = int(index.state.bmat.keys.shape[1])
+                    if presized:  # guards are charged as they run (no build)
+                        self._budget = max(
+                            self._budget - (time.perf_counter() - p0), 0.0
+                        )
+
+            # capacity-debt guard (analogous to LSM compaction-debt limits):
+            # a delta buffer about to overflow its capacity would force an
+            # organic reallocation — new jit shapes, mid-wave — so an
+            # absorb retrain is mandatory no matter what the policy
+            # prefers. It watches the FULLEST buffer, not the (heat-biased)
+            # focus shard — any shard can hit the debt limit. This also
+            # keeps learning safe: the controller explores within bounds
+            # the scheduler enforces. With async builds the forced absorb
+            # becomes an urgent *plan*; while one is already in flight the
+            # buffer may organically grow once, which the monotone shape
+            # discipline absorbs.
+            hot = int(np.argmax(snap.bmat_size))
+            forced = (
+                int(snap.bmat_size[hot]) > 0
+                and float(snap.bmat_size[hot])
+                > self.cfg.force_absorb_fill * bcap
+            )
+
+            # close the reward loop for the previous learned action on the
+            # normal cadence (Algorithm 1 lines 13-17) — even when a forced
+            # absorb preempts this wave's choice, so the old action's reward
+            # window doesn't silently stretch over later maintenance stalls
+            if decide and self._pending is not None:
+                p_state, p_action, _ = self._pending
+                r = self.controller.reward(
+                    snap.throughput_ewma, snap.memory_ewma,
+                    snap.range_lat_ewma,
+                )
+                self.controller.update(p_state, p_action, r, state, mask)
+                self._pending = None
+
+            a, deferred = A_KEEP, False
+            s_apply = s
+            if forced:
+                a, s_apply = A_RETRAIN_SHARD, hot
+            elif decide:
+                a = self.controller.choose(
+                    state, mask, explore=self.cfg.explore,
+                    snap=snap, s=s, heat=heat,
+                )
+            elif not presized and committed == 0 and drained == 0:
+                return None
+
+        with obs.span("tuner.act"):
+            # -- translate the decision into a plan / direct action -------
+            changed = False
+            if a in BUILD_ACTIONS:
+                if a == A_MERGE_SHARDS:
+                    s_apply = self.controller.coldest_pair(snap)
+                if not self._admit(index, a, s_apply, forced):
+                    # no free worker slot, interval overlaps an in-flight
+                    # build / draining commit, or unaffordable — defer
+                    a, deferred = A_KEEP, True
+                else:
                     self.controller.action_counts[a] += 1
-                    self._charge(A_SWITCH_LOCATE, time.perf_counter() - sw0)
-                else:  # telemetry moved since the mask: nothing to change
-                    a = A_KEEP
-                    self.controller.action_counts[A_KEEP] += 1
-        else:
-            self.controller.action_counts[A_KEEP] += 1
+                    changed = self._dispatch(
+                        index, self._make_plan(a, s_apply, forced)
+                    )
+            elif a == A_SWITCH_BMAT:
+                if self.pressure >= 1:
+                    a, deferred = A_KEEP, True  # shed: no structural changes
+                elif self._inflight or index.active_intervals():
+                    # the switch revises the WHOLE keyspace: it would void
+                    # every in-flight build and draining commit
+                    a, deferred = A_KEEP, True
+                elif self._estimated_cost(a) > self._available():
+                    a, deferred = A_KEEP, True
+                else:
+                    self.controller.action_counts[a] += 1
+                    sw0 = time.perf_counter()  # own timer: t0 covers commits
+                    index.switch_bmat_type()
+                    self._charge(A_SWITCH_BMAT, time.perf_counter() - sw0)
+                    changed = True
+            elif a == A_SWITCH_LOCATE:
+                # metadata-only: no arrays move, results are byte-identical
+                # across strategies, so — unlike switch_bmat — the repin
+                # needs neither an in-flight-build veto nor a revision
+                # record; only overload sheds it (the flipped wave may pay
+                # one jit variant)
+                if self.pressure >= 1:
+                    a, deferred = A_KEEP, True
+                elif self._estimated_cost(a) > self._available():
+                    a, deferred = A_KEEP, True
+                else:
+                    pick = self.controller.pick_locate(snap, s)
+                    sw0 = time.perf_counter()
+                    changed = index.set_shard_locate(s, pick)
+                    if changed:
+                        self.controller.action_counts[a] += 1
+                        self._charge(
+                            A_SWITCH_LOCATE, time.perf_counter() - sw0
+                        )
+                    else:  # telemetry moved since the mask: nothing to change
+                        a = A_KEEP
+                        self.controller.action_counts[A_KEEP] += 1
+            else:
+                self.controller.action_counts[A_KEEP] += 1
 
-        dt = time.perf_counter() - t0
-        self.time_in_maintenance += dt
-        if decide and not forced and (self.cfg.explore or a != A_KEEP):
-            self._pending = (state, a, mask)
+            dt = time.perf_counter() - t0
+            self.time_in_maintenance += dt
+            if decide and not forced and (self.cfg.explore or a != A_KEEP):
+                self._pending = (state, a, mask)
 
-        rec = {
-            "wave": self._wave,
-            "shard": s_apply,
-            "action": ACTION_NAMES[a],
-            "changed": bool(changed),
-            "deferred": deferred,
-            "forced": forced,
-            "presized": presized,
-            "committed": committed,
-            "drained": drained,
-            "pressure": self.pressure,
-            "draining": len(index.draining_builds()),
-            "replayed_ops": index.n_replayed_ops - replayed0,
-            "inflight": len(self._inflight),
-            "cost_s": dt,
-            "budget_s": self._budget,
-            "reserved_s": self._reserved,
-            "throughput_ewma": snap.throughput_ewma,
-            "n_shards": snap.n_shards,
-            "bmat_fill_max": float(snap.bmat_fill.max()),
-        }
-        self.actions_log.append(rec)
+            rec = {
+                "wave": self._wave,
+                "shard": s_apply,
+                "action": ACTION_NAMES[a],
+                "changed": bool(changed),
+                "deferred": deferred,
+                "forced": forced,
+                "presized": presized,
+                "committed": committed,
+                "drained": drained,
+                "pressure": self.pressure,
+                "draining": len(index.draining_builds()),
+                "replayed_ops": index.n_replayed_ops - replayed0,
+                "inflight": len(self._inflight),
+                "cost_s": dt,
+                "budget_s": self._budget,
+                "reserved_s": self._reserved,
+                "throughput_ewma": snap.throughput_ewma,
+                "n_shards": snap.n_shards,
+                "bmat_fill_max": float(snap.bmat_fill.max()),
+            }
         return rec

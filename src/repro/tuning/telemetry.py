@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.bmat import bmat_height
 from repro.core.sharded import ShardedUpLIF
 from repro.core.state import UpLIFState
@@ -182,10 +183,11 @@ class Telemetry:
                     lat if prev is None else (1 - w) * prev + w * lat
                 )
 
+    @obs.traced("tuner.telemetry")
     def snapshot(self, index: ShardedUpLIF) -> TelemetrySnapshot:
         """Read the per-shard signals (one device reduce + one transfer)."""
         self.observe_locate(index.drain_locate_obs(), index.n_shards)
-        sig = jax.device_get(shard_signals(index.state))
+        sig = obs.fetch("tuner.telemetry", shard_signals(index.state))
         bsz = np.asarray(sig.bmat_size)
         heights = np.asarray(
             [

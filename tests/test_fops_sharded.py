@@ -293,3 +293,70 @@ def test_policy_masks_disabled_actions():
     agent._q_row(s)[A_RETRAIN] = 1.0
     assert agent.choose(s, explore=False) == A_RETRAIN
     assert agent.policy()[s] == A_RETRAIN, "policy() must mask like choose()"
+
+
+# ---------------------------------------------------------------------------
+# what the write path reports and how its device phases are named
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n_shards", [1, 4])
+def test_sinsert_round_keys_count_only_keys_not_yet_stored(n_shards):
+    """Upserts of stored keys are resolved by the probe, so the accept
+    rounds get none of them; fresh keys all reach the rounds."""
+    from repro import obs
+
+    keys = make_keys(4000, 303)
+    idx = ShardedUpLIF(keys, keys, CFG, n_shards=n_shards)
+    rec = obs.RECORDER
+
+    def wave(batch):
+        k0, r0 = rec.counter("sinsert.keys"), rec.counter("sinsert.round_keys")
+        idx.insert(batch, batch * 5, pad_to=512)
+        return (rec.counter("sinsert.keys") - k0,
+                rec.counter("sinsert.round_keys") - r0)
+
+    stored = np.random.default_rng(304).choice(keys, 300, replace=False)
+    assert wave(stored) == (300, 0)
+    fresh = np.setdiff1d(
+        np.random.default_rng(305).integers(0, 1 << 48, 400), keys
+    )[:300]
+    assert wave(fresh) == (len(fresh), len(fresh))
+    assert wave(fresh) == (len(fresh), 0)   # now stored (slots or BMAT)
+    found, vals = idx.lookup(np.concatenate([stored, fresh]))
+    assert found.all() and (vals == np.concatenate([stored, fresh]) * 5).all()
+
+
+def test_insert_reports_round_keys():
+    keys = make_keys(3000, 306)
+    idx = UpLIF(keys, keys, CFG)
+    fresh = np.setdiff1d(
+        np.random.default_rng(307).integers(0, 1 << 48, 200), keys
+    )[:100]
+    batch = np.concatenate([keys[:50], fresh])
+    idx._ensure_bmat_capacity(256)
+    _, res = fops.insert(idx.fstate, _pad(batch, KEY_MAX), _pad(batch, 0),
+                         static=idx.fstatic())
+    assert int(res.n_round_keys) == len(fresh)
+
+
+def test_device_phases_are_named_in_the_lowered_programs():
+    """``sinsert``'s probe, accept rounds and merge, and ``slookup``'s
+    locate and rank, carry ``jax.named_scope`` names a profiler trace can
+    group device operations by."""
+    import re
+
+    keys = make_keys(3000, 308)
+    idx = ShardedUpLIF(keys, keys, CFG, n_shards=2)
+    q = _pad(keys[:10], KEY_MAX)
+    args = (idx.state, q, idx._jbounds, idx._jcodes)
+    look = fops.slookup.lower(*args, static=idx._static())
+    ins = fops.sinsert.lower(idx.state, q, q, idx._jbounds, idx._jcodes,
+                             static=idx._static())
+
+    def scopes(lowered, fn):
+        text = lowered.as_text(debug_info=True)
+        return set(re.findall(rf"jit\({fn}\)/(\w+)/", text))
+
+    assert {"locate", "rank"} <= scopes(look, "slookup")
+    assert {"probe", "rounds", "merge"} <= scopes(ins, "sinsert")

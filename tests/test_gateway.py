@@ -213,6 +213,7 @@ def test_overload_sheds_maintenance_before_rejecting_reads():
         _SlowIndex(idx), tuner=tuner,
         config=GatewayConfig(max_batch=8, max_delay_s=0.001, max_pending=40),
     )
+    events0 = gw.stats()["pressure_events"]
     try:
         rejected_at = None
         futs = []
@@ -236,6 +237,61 @@ def test_overload_sheds_maintenance_before_rejecting_reads():
         gw.close()
     # recovery: once drained, the gateway reports pressure 0 downstream
     assert tuner.pressure_calls[-1][1] == 0
+    # one pressure event per level change the tuner was told of
+    assert gw.stats()["pressure_events"] - events0 == len(tuner.pressure_calls)
+
+
+def test_stats_obs_nests_router_and_tuner_spans_under_each_wave():
+    from repro import obs
+
+    idx, keys = _mk_index()
+    tuner = SelfTuner().attach(idx)
+    gw = RequestGateway(
+        idx, tuner=tuner, config=GatewayConfig(max_batch=64, max_delay_s=0.001)
+    )
+    before = gw.stats()
+    t0 = time.perf_counter_ns()
+    try:
+        for i in range(3):
+            futs = [gw.submit_insert(int(k) + 1, 5) for k in keys[i * 40:(i + 1) * 40]]
+            futs += [gw.submit_lookup(int(k)) for k in keys[:50]]
+            for f in futs:
+                f.result(30.0)
+    finally:
+        gw.close()
+        tuner.close()
+    after = gw.stats()
+    waves = after["waves"] - before["waves"]
+    assert waves >= 3
+
+    def delta(name):
+        b = before["obs"]["spans"].get(name, {"count": 0})["count"]
+        return after["obs"]["spans"][name]["count"] - b
+
+    assert delta("gateway.wave") == waves
+    assert delta("gateway.drain") == delta("gateway.complete") == waves
+    assert delta("router.apply_wave") == delta("tuner.after_wave") == waves
+    syncs = after["obs"]["counters"]["host_syncs"]
+    assert syncs > before["obs"]["counters"].get("host_syncs", 0)
+    assert after["obs"]["counters"]["host_syncs.router.lookup"] > 0
+
+    spans = obs.RECORDER.spans(t0, time.perf_counter_ns())
+    by_id = {s.id: s for s in spans}
+    top = {s.wave: s for s in spans if s.name == "gateway.wave"}
+    assert len(top) == waves
+    for s in spans:
+        if s.name.split(".")[0] not in ("router", "tuner", "gateway"):
+            continue
+        assert s.wave in top, s
+        root = s
+        while root.parent != -1:
+            root = by_id[root.parent]
+            assert root.wave == s.wave
+        assert root is top[s.wave]
+    names = {s.name for s in spans}
+    assert {"router.insert", "router.lookup", "router.launch", "router.wait",
+            "tuner.observe_inserts", "tuner.forecast", "tuner.telemetry",
+            "tuner.decide"} <= names
 
 
 # ------------------------------------------------------ read-your-writes
