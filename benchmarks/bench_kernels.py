@@ -100,7 +100,7 @@ def run(n_keys: int = 200_000, q: int = 4096, seed: int = 0):
     mu = jnp.asarray([-1.0, 0.0, 2.0])
     sd = jnp.asarray([0.5, 1.0, 0.7])
     dt = time_batches(
-        lambda: ops.gmm_estep(x, w, mu, sd).block_until_ready(), n_iters=5
+        lambda: ops.gmm_estep(x, w, mu, sd), n_iters=5
     )
     rows.append({"name": "gmm_estep", "us_per_call": round(dt * 1e6, 1),
                  "derived": f"{16384/dt/1e6:.3f} Msamples/s (interpret)"})

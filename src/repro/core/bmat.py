@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.shapes import pow2_at_least as _ceil_pow2  # §7.5 shared quant
+from repro.shapes import pow2_at_least as _ceil_pow2  # §7.5 shared quant
 from repro.core.types import BMATState, KEY_MAX, TOMBSTONE
 
 RBMAT = "rbmat"

@@ -57,7 +57,7 @@ import numpy as np
 from repro import obs
 from repro.core import fops
 from repro.core.bmat import BMAT, BPMAT, RBMAT, _make_fences, bmat_height
-from repro.core.shapes import grow_capacity, pow2_at_least
+from repro.shapes import grow_capacity, pow2_at_least
 from repro.core.state import (
     LOCATE_FUSED,
     LOCATE_STRATEGIES,
@@ -251,7 +251,7 @@ class MixedWave:
 
     This is the gateway's dispatch unit (serve/gateway.py): each op kind
     carries its own batch plus an optional pre-quantized pad width
-    (``pad_*``, a power of two from ``core/shapes.padded_width``). When a
+    (``pad_*``, a power of two from ``repro/shapes.padded_width``). When a
     pad width is given the router pads to exactly that width instead of
     the bulk ``bucket_width`` family — a live request stream has no
     repeating batch sizes, so only the power-of-two family keeps the jit
@@ -465,7 +465,7 @@ class ShardedUpLIF:
     # -- stacking ------------------------------------------------------------
     @staticmethod
     def _quant(n: int) -> int:
-        return pow2_at_least(n)  # §7.5 shared quantization (core/shapes.py)
+        return pow2_at_least(n)  # §7.5 shared quantization (repro/shapes.py)
 
     def _restack(self, shells: List[UpLIF]):
         """Pad every shard's state to common shapes and stack leaf-wise.

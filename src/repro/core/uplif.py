@@ -33,7 +33,8 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import fops, shapes
+from repro import shapes
+from repro.core import fops
 from repro.core.bmat import BMAT, BPMAT
 from repro.core.gmm import fit_gmm, gmm_memory_bytes, init_gmm_uniform
 from repro.core.nullifier import nullify
@@ -88,7 +89,7 @@ class UpLIFConfig:
         assert self.locate in LOCATE_STRATEGIES + (LOCATE_AUTO,)
 
 
-# Re-exported from the shared §7.5 quantization module (core/shapes.py) —
+# Re-exported from the shared §7.5 quantization module (repro/shapes.py) —
 # the shell, the shard router and the serving gateway must bucket
 # identically or their jit caches diverge.
 bucket_width = shapes.bucket_width

@@ -25,6 +25,7 @@ from repro.kernels.spline_lookup import (
     spline_lookup_pallas,
 )
 from repro.kernels.tile_search import Q_BLK as TS_Q_BLK, TILE, tile_search_pallas
+from repro.shapes import pow2_at_least
 
 MAX_VMEM_KEYS = 131072  # ~1MB hi/lo in VMEM; larger buffers use tile fallback
 MAX_VMEM_SLOTS = 1 << 20   # fused-locate slot residency guard (8MB hi/lo)
@@ -315,11 +316,36 @@ def bmat_rank(keys, fences, queries, fanout: int):
 # -- gmm e-step ---------------------------------------------------------------
 
 
-def gmm_estep(x, weights, means, stds):
-    x32 = x.astype(jnp.float32)
-    w32 = weights.astype(jnp.float32)
-    m32 = means.astype(jnp.float32)
-    s32 = stds.astype(jnp.float32)
-    x32, n = _pad_to(x32, GMM_N_BLK, 0.0)
-    out = gmm_estep_pallas(x32, w32, m32, s32, interpret=interpret_mode())
-    return out[:n]
+def gmm_estep_width(n: int) -> int:
+    """Lanes the E-step runs for ``n`` samples: the next power of two ≥ n,
+    at least one block. The width follows n in powers of two, so the
+    E-step compiles once per width and never for a batch length."""
+    return max(pow2_at_least(n), GMM_N_BLK)
+
+
+@jax.jit
+def _gmm_estep_padded(xp, weights, means, stds, lo, span, std_floor):
+    ms = (means - lo) / span
+    ss = jnp.maximum(stds / span, std_floor)
+    return gmm_estep_pallas(
+        xp.astype(jnp.float32), weights.astype(jnp.float32),
+        ms.astype(jnp.float32), ss.astype(jnp.float32),
+        interpret=interpret_mode(),
+    )
+
+
+def gmm_estep(x, weights, means, stds, *, lo=0.0, span=1.0, std_floor=0.0,
+              fetch=np.asarray):
+    """(N, K) float32 responsibilities of ``x`` as a host array, under the
+    mixture mapped by ``(· − lo) / span`` with stds floored at
+    ``std_floor``. ``x`` is padded on the host with 0.0 to
+    ``gmm_estep_width(N)`` lanes; one jitted program holds the mapping,
+    the float32 casts, the kernel and the transpose, so the mixture may
+    stay on the device. ``fetch`` reads the (W, K) result to the host,
+    where the padded rows are cut."""
+    x = np.asarray(x)
+    n = x.shape[0]
+    xp = np.zeros(gmm_estep_width(n), x.dtype)
+    xp[:n] = x
+    out = _gmm_estep_padded(xp, weights, means, stds, lo, span, std_floor)
+    return np.asarray(fetch(out))[:n]

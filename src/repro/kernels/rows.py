@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.shapes import pow2_at_least
+
 LANES = 128
 Q_TILE = 1024
 _PAD = 8 * LANES  # whole (8, 128) tiles
@@ -62,7 +64,7 @@ def pad_queries(n: int) -> int:
     """Padded batch length for the search kernels: a power of two from 256
     up to 1024, then a multiple of 1024 (see ``q_block``)."""
     if n <= Q_TILE:
-        return max(256, 1 << max(0, n - 1).bit_length())
+        return max(256, pow2_at_least(n))
     return -(-n // Q_TILE) * Q_TILE
 
 

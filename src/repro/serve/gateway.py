@@ -20,7 +20,7 @@ Three disciplines, one per layer of the ROADMAP contract:
   whichever comes first: bounded batching delay under trickle load, full
   amortization under heavy load.
 * **shape quantization** — every flush pads to the §7.5 power-of-two
-  family (``core/shapes.padded_width``), so a continuous sweep of
+  family (``repro/shapes.padded_width``), so a continuous sweep of
   offered loads exercises exactly the warmup set of jit variants —
   ``warmup()`` primes them all and the compile count never moves again
   (the bench_gateway acceptance check).
@@ -45,7 +45,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from repro import obs
-from repro.core.shapes import padded_width, pow2_at_least
+from repro.shapes import padded_width, pow2_at_least
 from repro.core.sharded import MixedWave, ShardedUpLIF
 from repro.core.types import KEY_MAX
 from repro.serve.admission import AdmissionController, RetryAfter

@@ -14,18 +14,19 @@ insert stream live and drives three proactive decisions:
 
 Estimation is stepwise EM over decayed sufficient statistics (Cappé &
 Moulines 2009): each observed batch contributes one E-step — the dense
-(N, K) responsibility kernel, run through the Pallas E-step
-(repro/kernels/gmm_estep.py) with the pure-JAX ``core.gmm.e_step`` as
-fallback — followed by a closed-form M-step on the decayed stats. Old
-batches decay geometrically, so the mixture tracks shift at a rate set by
-``decay`` instead of averaging over the whole history. Keys are mapped to
-the unit interval before the f32 kernel so 52-bit magnitudes don't eat the
+(N, K) responsibilities, through the Pallas E-step
+(repro/kernels/gmm_estep.py) on a TPU and numpy elsewhere — followed
+by a closed-form M-step on the decayed stats. Old batches decay
+geometrically, so the mixture tracks shift at a rate set by ``decay``
+instead of averaging over the whole history. Keys are mapped to the unit
+interval before the f32 kernel so 52-bit magnitudes don't eat the
 mantissa; responsibilities are scale-invariant, the stats are accumulated
 in f64 on the raw keys.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax.numpy as jnp
@@ -89,19 +90,22 @@ class UpdateForecaster:
     def _responsibilities(self, x: np.ndarray) -> np.ndarray:
         """(N, K) responsibilities under the current mixture."""
         if self.cfg.use_pallas:
-            from repro.kernels.ops import gmm_estep
+            from repro.kernels.ops import gmm_estep, gmm_estep_width
 
             # unit-domain scaling keeps the f32 kernel conditioned on
             # 52-bit keys; the shared -log(span) shifts every component
-            # equally and cancels in the softmax
-            xs = jnp.asarray((x - self.lo) / self.span)
-            ms = (self.gmm.means - self.lo) / self.span
-            ss = jnp.maximum(self.gmm.stds / self.span, _MIN_STD_FRAC)
-            return np.asarray(
-                obs.fetch("forecast.estep",
-                          gmm_estep(xs, self.gmm.weights, ms, ss)),
-                dtype=np.float64,
+            # equally and cancels in the softmax. The mixture is mapped
+            # inside the E-step's one program, so it stays on the device.
+            n = len(x)
+            obs.count("forecast.estep.keys", n)
+            obs.count("forecast.estep.lanes", gmm_estep_width(n))
+            g = self.gmm
+            resp = gmm_estep(
+                (x - self.lo) / self.span, g.weights, g.means, g.stds,
+                lo=self.lo, span=self.span, std_floor=_MIN_STD_FRAC,
+                fetch=functools.partial(obs.fetch, "forecast.estep"),
             )
+            return resp.astype(np.float64)
         # host path: a K-component E-step over numpy is microseconds per
         # batch and — unlike a jitted path — indifferent to the batch
         # length, so the per-wave observe never compiles anything

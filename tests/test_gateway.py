@@ -11,7 +11,7 @@ from conftest import make_keys
 
 from repro.core import fops
 from repro.core.sharded import ShardedUpLIF
-from repro.core.shapes import (
+from repro.shapes import (
     bucket_width,
     grow_capacity,
     padded_width,

@@ -9,6 +9,10 @@ import jax.numpy as jnp
 from repro.core import ShardedUpLIF
 from repro.core.gmm import e_step, gmm_cdf, gmm_cdf_np, init_gmm_uniform
 from repro.core.uplif import UpLIFConfig
+from repro import obs
+from repro.kernels import ops as kops, ref as kref
+from repro.kernels.gmm_estep import N_BLK as GMM_N_BLK, gmm_estep_pallas
+from repro.serve import GatewayConfig, RequestGateway
 from repro.tuning import (
     ACTIONS,
     A_KEEP,
@@ -75,6 +79,119 @@ def test_forecaster_pallas_estep_matches_oracle():
     assert fc.cfg.use_pallas, "pallas path must not have silently degraded"
     oracle, _ = e_step(fc.gmm, jnp.asarray(x, dtype=jnp.float64))
     np.testing.assert_allclose(resp_k, np.asarray(oracle), atol=2e-3)
+
+
+def _estep_eager(x, weights, means, stds):
+    """The E-step dispatch as it stood before the padded program: eager
+    casts, a device-side pad to a multiple of the block, an eager slice."""
+    x32 = x.astype(jnp.float32)
+    n = x32.shape[0]
+    m = -(-n // GMM_N_BLK) * GMM_N_BLK
+    if m != n:
+        x32 = jnp.concatenate([x32, jnp.full((m - n,), 0.0, x32.dtype)])
+    out = gmm_estep_pallas(
+        x32, weights.astype(jnp.float32), means.astype(jnp.float32),
+        stds.astype(jnp.float32), interpret=True,
+    )
+    return out[:n]
+
+
+class _EagerForecaster(UpdateForecaster):
+    """The forecaster's Pallas branch as it stood before the padded
+    program, over ``_estep_eager``."""
+
+    def _responsibilities(self, x):
+        xs = jnp.asarray((x - self.lo) / self.span)
+        ms = (self.gmm.means - self.lo) / self.span
+        ss = jnp.maximum(self.gmm.stds / self.span, 1e-6)
+        return np.asarray(_estep_eager(xs, self.gmm.weights, ms, ss),
+                          dtype=np.float64)
+
+
+def _estep_args(n, k=4, seed=0):
+    r = np.random.default_rng(seed + n)
+    return (jnp.asarray(r.normal(0, 5, n)),
+            jnp.asarray(r.dirichlet(np.ones(k))),
+            jnp.asarray(np.linspace(-4, 4, k)),
+            jnp.asarray(r.uniform(0.5, 2.0, k)))
+
+
+@pytest.mark.parametrize("n", [1, 700, 1013, 1024, 2047, 2049, 8192])
+def test_gmm_estep_padded_matches_eager_dispatch(n):
+    """Host padding and one program per width give the eager dispatch's
+    numbers bit for bit, and the oracle's at the sweep's tolerance."""
+    x, w, mu, sd = _estep_args(n)
+    got = kops.gmm_estep(x, w, mu, sd)
+    assert got.shape == (n, 4) and got.dtype == np.float32
+    np.testing.assert_array_equal(got, np.asarray(_estep_eager(x, w, mu, sd)))
+    f32 = lambda a: a.astype(jnp.float32)
+    gold = np.asarray(kref.gmm_estep_ref(f32(x), f32(w), f32(mu), f32(sd)))
+    np.testing.assert_allclose(got, gold, atol=1e-5)
+
+
+def _compiles():
+    return sum(v for k, v in obs.RECORDER.snapshot()["counters"].items()
+               if k.startswith("compiles."))
+
+
+def test_gmm_estep_compiles_once_per_width():
+    """Batch lengths within one power-of-two width reuse one program; the
+    widths 4096 and 8192 add one program each."""
+    _, w, mu, sd = _estep_args(1)
+    x = np.random.default_rng(1).normal(0, 5, 8192)
+    kops.gmm_estep(x[:1000], w, mu, sd)
+    size, compiles = kops._gmm_estep_padded._cache_size(), _compiles()
+    for n in np.random.default_rng(2).choice(np.arange(1, 2049), 50,
+                                             replace=False):
+        kops.gmm_estep(x[:n], w, mu, sd)
+    assert kops._gmm_estep_padded._cache_size() == size
+    assert _compiles() == compiles
+    for n in (2049, 3000, 4096, 4097, 6000, 8192):
+        kops.gmm_estep(x[:n], w, mu, sd)
+    assert kops._gmm_estep_padded._cache_size() <= size + 2
+
+
+def test_forecaster_padded_estep_matches_eager_stream():
+    """A stream of batches of changing lengths ends in the eager dispatch's
+    mixture and drift; the padding counters reach the gateway's stats."""
+    rng = np.random.default_rng(4)
+    cfg = ForecastConfig(use_pallas=True, max_batch=4096, seed=3)
+    lo, hi = float(1 << 40), float(1 << 41)
+    new = UpdateForecaster(lo, hi, cfg)
+    old = _EagerForecaster(lo, hi, cfg)
+    keys0 = obs.RECORDER.counter("forecast.estep.keys")
+    lanes0 = obs.RECORDER.counter("forecast.estep.lanes")
+    for i, n in enumerate((300, 1013, 700, 2048, 1500, 5000, 64, 999)):
+        centre = lo + (0.2 + 0.07 * i) * (hi - lo)
+        x = (centre + rng.normal(0, 2e9, n)).astype(np.int64)
+        new.observe(x)
+        old.observe(x)
+    for a, b in ((new.gmm.weights, old.gmm.weights),
+                 (new.gmm.means, old.gmm.means),
+                 (new.gmm.stds, old.gmm.stds)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-12)
+    np.testing.assert_allclose(new.drift_ewma, old.drift_ewma, rtol=1e-12)
+    assert new.drift_ewma > 0
+    sent = 300 + 1013 + 700 + 2048 + 1500 + 4096 + 64 + 999
+    assert obs.RECORDER.counter("forecast.estep.keys") - keys0 == sent
+    assert (obs.RECORDER.counter("forecast.estep.lanes") - lanes0
+            == 7 * 2048 + 4096)
+
+    keys = make_keys(4096, 11)
+    idx = ShardedUpLIF(keys, keys * 2, CFG, n_shards=2)
+    tuner = SelfTuner(TunerConfig(forecast=cfg)).attach(idx)
+    gw = RequestGateway(
+        idx, tuner=tuner, config=GatewayConfig(max_batch=64, max_delay_s=0.001)
+    )
+    try:
+        for f in [gw.submit_insert(int(k) + 1, 5) for k in keys[:100:2]]:
+            f.result(30.0)
+    finally:
+        gw.close()
+        tuner.close()
+    counters = gw.stats()["obs"]["counters"]
+    assert counters["forecast.estep.keys"] - keys0 >= sent + 50
+    assert counters["forecast.estep.lanes"] > counters["forecast.estep.keys"]
 
 
 def test_forecaster_gap_sizes_follow_forecast():
