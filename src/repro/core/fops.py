@@ -61,6 +61,7 @@ class InsertResult(NamedTuple):
     pending: jnp.ndarray     # bool[n] — keys still unplaced after the rounds
     n_overflow: jnp.ndarray  # int64 — count routed to the BMAT this call
     n_round_keys: jnp.ndarray  # int64 — keys pending when the rounds start
+    n_rounds: jnp.ndarray    # int32 — accept rounds the call ran
 
 
 class RangeResult(NamedTuple):
@@ -583,7 +584,8 @@ def insert(
         halves=halves,
     )
     return new_state, InsertResult(
-        pending=pending, n_overflow=n_over, n_round_keys=n_round_keys
+        pending=pending, n_overflow=n_over, n_round_keys=n_round_keys,
+        n_rounds=jnp.asarray(rounds, dtype=jnp.int32),
     )
 
 
@@ -1247,13 +1249,20 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
 
     rounds = max(1, static.insert_rounds)
 
-    def accept_round(i, carry):
+    def accept_round(carry):
         """One global grid-segment accept over the flat view. The rounds
         run as a loop, not unrolled: the TPU compiler then compiles the
-        round once instead of once per round. Locates as in ``insert``:
-        the probe's serves round 0, and the last round locates nothing."""
-        (sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran,
-         j, icap) = carry
+        round once instead of once per round. The probe's locate serves
+        round 0; a later round first locates over the slots the round
+        before it rewrote."""
+        (i, _, sk, sv, so, slot_halves, pending, n_keys, n_inplace,
+         min_gran, j, icap) = carry
+        if rounds > 1:
+            j, icap = jax.lax.cond(
+                i > 0,
+                lambda: locate(sk, slot_halves, pending)[1:],
+                lambda: (j, icap),
+            )
         qk = jnp.where(pending, keys, KEY_MAX)
         ins_slot = jnp.clip(jnp.minimum(j + 1, icap), 0, cap - 1)
         bucket = jnp.where(
@@ -1278,18 +1287,23 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
             jnp.where(failed_span < _I64_MAX, sid_w, S)
         ].min(failed_span, mode="drop")
         pending = pending & ~jnp.zeros(N, dtype=bool).at[order].set(ok)
-        j, icap = _next_locate(i, rounds, locate, sk, slot_halves, pending,
-                               j, icap)
-        return (sk, sv, so, slot_halves, pending, n_keys + ok_per,
-                n_inplace + ok_per, jnp.minimum(min_gran, span_per), j, icap)
+        return (i + 1, jnp.sum(ok, dtype=jnp.int32), sk, sv, so, slot_halves,
+                pending, n_keys + ok_per, n_inplace + ok_per,
+                jnp.minimum(min_gran, span_per), j, icap)
+
+    def can_place(carry):
+        """Run a round while a key is pending and the round before placed
+        one: a round that places none leaves the slots, ``pending`` and
+        the counters as they were, so every later round would repeat it."""
+        i, n_placed, pending = carry[0], carry[1], carry[6]
+        return (i < rounds) & jnp.any(pending) & ((i == 0) | (n_placed > 0))
 
     with jax.named_scope("rounds"):
-        sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran, _, _ = (
-            jax.lax.fori_loop(
-                0, rounds, accept_round,
-                (sk, sv, so, slot_halves, pending, n_keys, c.n_inplace,
-                 c.min_granularity, j, icap),
-            )
+        (n_rounds, _, sk, sv, so, slot_halves, pending, n_keys, n_inplace,
+         min_gran, _, _) = jax.lax.while_loop(
+            can_place, accept_round,
+            (jnp.int32(0), jnp.int32(0), sk, sv, so, slot_halves, pending,
+             n_keys, c.n_inplace, c.min_granularity, j, icap),
         )
 
     if halves is not None:
@@ -1325,7 +1339,7 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
     )
     return new_state, InsertResult(
         pending=pending, n_overflow=jnp.sum(n_over),
-        n_round_keys=n_round_keys,
+        n_round_keys=n_round_keys, n_rounds=n_rounds,
     )
 
 

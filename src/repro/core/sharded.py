@@ -807,9 +807,11 @@ class ShardedUpLIF:
         pad_to: Optional[int] = None,
     ) -> int:
         """Upsert a batch; returns how many keys went to the BMAT. Counts
-        the batch's keys (``sinsert.keys``), those still pending when the
-        accept rounds start (``sinsert.round_keys``) and those that went to
-        the BMAT (``sinsert.bmat_keys``)."""
+        the calls (``sinsert.calls``), the batch's keys (``sinsert.keys``),
+        those still pending when the accept rounds start
+        (``sinsert.round_keys``), the accept rounds run
+        (``sinsert.rounds``) and the keys that went to the BMAT
+        (``sinsert.bmat_keys``)."""
         with obs.span("router.prepare"):
             keys = np.asarray(keys, dtype=np.int64)
             if vals is None:
@@ -831,11 +833,14 @@ class ShardedUpLIF:
         with self._lock:
             self.state = state
         with obs.span("router.wait"):
-            n_over, n_round = obs.fetch(
-                "router.insert", (res.n_overflow, res.n_round_keys)
+            n_over, n_round, n_rounds = obs.fetch(
+                "router.insert",
+                (res.n_overflow, res.n_round_keys, res.n_rounds),
             )
+        obs.count("sinsert.calls")
         obs.count("sinsert.keys", n)
         obs.count("sinsert.round_keys", int(n_round))
+        obs.count("sinsert.rounds", int(n_rounds))
         obs.count("sinsert.bmat_keys", int(n_over))
         return int(n_over)
 

@@ -106,11 +106,15 @@ def test_insert_preserves_slot_invariants():
     r.shuffle(new)
     idx.insert(new, new)
     idx.delete(keys[::7])
-    sk = np.asarray(idx.slots.keys)
-    so = np.asarray(idx.slots.occ)
-    assert np.all(np.diff(sk) >= 0), "slot keys must stay sorted"
     assert idx.capacity % idx.cfg.window == 0, "W-aligned capacity"
-    # fill-forward: an empty slot holds the key of the next occupied slot
+    _assert_slot_invariants(np.asarray(idx.slots.keys),
+                            np.asarray(idx.slots.occ))
+
+
+def _assert_slot_invariants(sk, so):
+    """Slot keys sorted, and an empty slot holds the key of the next
+    occupied slot (fill-forward) or KEY_MAX."""
+    assert np.all(np.diff(sk) >= 0), "slot keys must stay sorted"
     nxt = None
     for i in range(len(sk) - 1, -1, -1):
         if so[i]:
@@ -303,28 +307,158 @@ def test_policy_masks_disabled_actions():
 @pytest.mark.parametrize("n_shards", [1, 4])
 def test_sinsert_round_keys_count_only_keys_not_yet_stored(n_shards):
     """Upserts of stored keys are resolved by the probe, so the accept
-    rounds get none of them; fresh keys all reach the rounds."""
+    rounds get none of them and none runs; fresh keys all reach the
+    rounds, which run at most ``insert_rounds`` times a call. The round
+    count travels in the one host sync the call already makes."""
     from repro import obs
 
     keys = make_keys(4000, 303)
     idx = ShardedUpLIF(keys, keys, CFG, n_shards=n_shards)
     rec = obs.RECORDER
+    names = ("sinsert.keys", "sinsert.round_keys", "sinsert.rounds",
+             "sinsert.calls", "host_syncs.router.insert")
 
     def wave(batch):
-        k0, r0 = rec.counter("sinsert.keys"), rec.counter("sinsert.round_keys")
+        before = [rec.counter(n) for n in names]
         idx.insert(batch, batch * 5, pad_to=512)
-        return (rec.counter("sinsert.keys") - k0,
-                rec.counter("sinsert.round_keys") - r0)
+        return dict(zip(names, (rec.counter(n) - b
+                                for n, b in zip(names, before))))
 
     stored = np.random.default_rng(304).choice(keys, 300, replace=False)
-    assert wave(stored) == (300, 0)
+    d = wave(stored)
+    assert (d["sinsert.keys"], d["sinsert.round_keys"]) == (300, 0)
+    assert (d["sinsert.rounds"], d["sinsert.calls"]) == (0, 1)
+    assert d["host_syncs.router.insert"] == 1
     fresh = np.setdiff1d(
         np.random.default_rng(305).integers(0, 1 << 48, 400), keys
     )[:300]
-    assert wave(fresh) == (len(fresh), len(fresh))
-    assert wave(fresh) == (len(fresh), 0)   # now stored (slots or BMAT)
+    d = wave(fresh)
+    assert (d["sinsert.keys"], d["sinsert.round_keys"]) == (300, 300)
+    assert 1 <= d["sinsert.rounds"] <= CFG.insert_rounds
+    assert d["sinsert.calls"] == d["host_syncs.router.insert"] == 1
+    d = wave(fresh)                          # now stored (slots or BMAT)
+    assert (d["sinsert.keys"], d["sinsert.round_keys"]) == (300, 0)
+    assert d["sinsert.rounds"] == 0
+    assert d["sinsert.calls"] == d["host_syncs.router.insert"] == 1
     found, vals = idx.lookup(np.concatenate([stored, fresh]))
     assert found.all() and (vals == np.concatenate([stored, fresh]) * 5).all()
+
+
+def _open_gaps(idx):
+    """Fresh keys by (shard, W-window): ``k + 1`` for each occupied slot
+    ``k`` in the middle half of its window that an empty slot follows,
+    with no key at ``k + 1``. Alone in its window such a key is placed in
+    the first round without a shift, and placing it leaves the window's
+    other such keys placeable."""
+    W = idx.cfg.window
+    sk = np.asarray(idx.state.slots.keys)
+    so = np.asarray(idx.state.slots.occ)
+    off = np.arange(sk.shape[1] - 1) % W
+    ok = (so[:, :-1] & ~so[:, 1:] & (sk[:, 1:] > sk[:, :-1] + 1)
+          & (off >= W // 4) & (off < 3 * W // 4))
+    gaps = {}
+    for s, i in zip(*np.nonzero(ok)):
+        gaps.setdefault((int(s), int(i) // W), []).append(int(sk[s, i]) + 1)
+    return gaps
+
+
+def _window_heads(idx):
+    """Fresh keys one above the occupied last slot of a W-window when the
+    next window holds nothing below them: the insertion slot opens the
+    next window, where no window insert can place a key (too near its
+    edge), so each goes to the BMAT."""
+    W = idx.cfg.window
+    sk = np.asarray(idx.state.slots.keys)
+    so = np.asarray(idx.state.slots.occ)
+    last, head = sk[:, W - 1:-1:W], sk[:, W::W]
+    ok = so[:, W - 1:-1:W] & (head > last + 1)
+    return (last[ok] + 1).astype(np.int64)
+
+
+def _sinsert_case(case, idx, keys):
+    rng = np.random.default_rng(310)
+    gaps = _open_gaps(idx)
+    if case == "stored":
+        return rng.choice(keys, 200, replace=False)
+    if case == "one_per_window":
+        return np.asarray([g[0] for g in list(gaps.values())[:40]])
+    if case in ("two_per_window", "five_per_window"):
+        per = 2 if case == "two_per_window" else 5
+        full = [g[:per] for g in gaps.values() if len(g) >= per]
+        assert len(full) >= 4
+        return np.concatenate(full[:10])
+    return _window_heads(idx)[:40]
+
+
+@pytest.mark.parametrize("case,n_rounds", [
+    ("stored", 0),            # the probe places every key
+    ("one_per_window", 1),    # one round places every key
+    ("two_per_window", 2),    # a window takes one key a round
+    ("five_per_window", 3),   # ... until insert_rounds; the rest: BMAT
+    ("window_heads", 1),      # a round that places none ends the loop
+])
+def test_sinsert_rounds_stop_when_no_key_can_be_placed(case, n_rounds):
+    """``sinsert`` at S = 8 runs its accept rounds only while a key is
+    pending and the round before placed one, and ends as the single-shard
+    ``fops.insert`` (which always runs ``insert_rounds``) does shard by
+    shard: the same slots, BMAT content, counters and lookups."""
+    keys = make_keys(8000, 311)
+    idx = ShardedUpLIF(keys, keys, CFG, n_shards=8)
+    batch = _sinsert_case(case, idx, keys).astype(np.int64)
+    vals = batch * 7
+    q, _, vm = idx._pad_route(batch, vals, width=256)
+    idx._ensure_bmat_capacity(int(q.shape[0]))
+
+    # the single-shard path on each shard's shell, before the stacked call
+    sid = idx._route(batch)
+    shells = []
+    for s in range(idx.n_shards):
+        sh = idx._unstack_shell(s)
+        m = sid == s
+        if m.any():
+            sq, _ = sh._pad(batch[m], KEY_MAX)
+            sv, _ = sh._pad(vals[m], 0)
+            sh._ensure_bmat_capacity(int(sq.shape[0]))
+            st, res = fops.insert(sh.fstate, sq, sv, static=sh.fstatic())
+            assert int(res.n_rounds) == CFG.insert_rounds
+            sh._adopt(st)
+        shells.append(sh)
+
+    state, res = fops.sinsert(idx.state, q, vm, idx._jbounds, idx._jcodes,
+                              static=idx._static())
+    assert int(res.n_rounds) == n_rounds
+    if case == "window_heads":
+        assert int(res.n_overflow) == len(batch) > 0
+    if case == "five_per_window":
+        assert 0 < int(res.n_overflow) < len(batch)
+
+    c = state.counters
+    for s, sh in enumerate(shells):
+        for f in ("n_keys", "n_bmat_live", "n_inplace", "n_overflow",
+                  "min_granularity"):
+            assert int(getattr(c, f)[s]) == int(getattr(sh._counters, f)), f
+        for a, b in zip(state.slots, sh.slots):
+            assert np.array_equal(np.asarray(a[s]), np.asarray(b))
+        n = int(state.bmat.size[s])
+        assert n == int(sh.bmat.state.size)
+        assert np.array_equal(np.asarray(state.bmat.keys[s, :n]),
+                              np.asarray(sh.bmat.state.keys[:n]))
+        assert np.array_equal(np.asarray(state.bmat.vals[s, :n]),
+                              np.asarray(sh.bmat.state.vals[:n]))
+        _assert_slot_invariants(np.asarray(state.slots.keys[s]),
+                                np.asarray(state.slots.occ[s]))
+
+    idx.state = state
+    allk = np.concatenate([keys, batch])
+    want = {int(k): int(k) for k in keys}
+    want.update((int(k), int(v)) for k, v in zip(batch, vals))
+    found, got = idx.lookup(allk)
+    assert found.all()
+    assert np.array_equal(got, [want[int(k)] for k in allk])
+    asid = idx._route(allk)
+    for s, sh in enumerate(shells):
+        f1, v1 = sh.lookup(allk[asid == s])
+        assert f1.all() and np.array_equal(v1, got[asid == s])
 
 
 def test_insert_reports_round_keys():
