@@ -99,19 +99,13 @@ def run_sharded(
 def run_locate_sweep(
     n_keys: int = 200_000, batch: int = 8192, n_iters: int = 11, seed: int = 0
 ):
-    """Locate-strategy sweep (ISSUE 5 + ISSUE 8): lookup + insert
-    throughput of the binsearch / spline / fused search plans over
-    identical index builds, single-shard AND stacked (S=4 — the stacked
-    fused path runs all shards in ONE kernel launch via per-query shard
-    base offsets). The fused strategy is measured under BOTH key
-    decompositions: ``persistent`` carries the (hi, lo) halves in the
-    state pytree (built once per state version, the default) and
-    ``percall`` re-splits the int64 arrays inside every dispatch (the old
-    behavior, kept as the regression baseline — CI fails if persistent
-    ever loses to it). Interleaved rounds, medians; off-TPU the fused
-    rows run the kernels in interpret mode, so they prove the wiring
-    rather than the TPU win — the decomposition delta is real either way,
-    since the split cost is jnp, not kernel, work.
+    """Locate-strategy sweep: lookup + insert throughput of the
+    binsearch / spline / fused search plans over identical index builds,
+    single-shard AND stacked (S=4 — the stacked fused path runs all shards
+    in ONE kernel launch via per-query shard base offsets; its adapters
+    split the int64 keys into (hi, lo) halves inside every dispatch).
+    Interleaved rounds, medians; off-TPU the fused rows run the kernels in
+    interpret mode, so they prove the wiring rather than the TPU speed.
 
     Every variant stays inside the fused kernels' shape guard: a delta
     buffer of at most MAX_VMEM_KEYS keys in all, and radix_bits=14 so the
@@ -123,18 +117,16 @@ def run_locate_sweep(
     init = keys[::2]
     fresh = np.setdiff1d(keys, init)
     rng.shuffle(fresh)
-    variants = []
-    for strat in ("binsearch", "spline", "fused"):
-        decomps = ("persistent", "percall") if strat == "fused" else ("-",)
-        for decomp in decomps:
-            for s in (1, 4):
-                tag = f"/{decomp}" if strat == "fused" else ""
-                variants.append((f"{strat}{tag}/S={s}", strat, s, decomp))
+    variants = [
+        (f"{strat}/S={s}", strat, s)
+        for strat in ("binsearch", "spline", "fused")
+        for s in (1, 4)
+    ]
     indexes = {}
-    for name, strat, s, decomp in variants:
+    for name, strat, s in variants:
         cfg = UpLIFConfig(
             bmat_capacity=min(n_keys, MAX_VMEM_KEYS), radix_bits=14,
-            locate=strat, persist_halves=decomp != "percall",
+            locate=strat,
         )
         indexes[name] = (
             UpLIF(init, init + 1, cfg)
@@ -143,7 +135,7 @@ def run_locate_sweep(
         )
 
     def check_strategies():
-        for name, strat, s, _ in variants:
+        for name, strat, s in variants:
             idx = indexes[name]
             ran = (
                 (idx.locate_strategy(),) if s == 1 else idx.shard_locate()
@@ -154,9 +146,9 @@ def run_locate_sweep(
     qs = rng.choice(init, batch).astype(np.int64)
     for idx in indexes.values():
         idx.lookup(qs)  # compile outside the timed rounds
-    look = {name: [] for name, _, _, _ in variants}
+    look = {name: [] for name, _, _ in variants}
     for _ in range(n_iters):
-        for name, _, _, _ in variants:
+        for name, _, _ in variants:
             t0 = time.perf_counter()
             indexes[name].lookup(qs)
             look[name].append(time.perf_counter() - t0)
@@ -168,9 +160,9 @@ def run_locate_sweep(
     for idx in indexes.values():
         for c in warm:
             idx.insert(c, c + 1)
-    ins = {name: [] for name, _, _, _ in variants}
+    ins = {name: [] for name, _, _ in variants}
     for c in timed:
-        for name, _, _, _ in variants:
+        for name, _, _ in variants:
             t0 = time.perf_counter()
             indexes[name].insert(c, c + 1)
             ins[name].append(time.perf_counter() - t0)
@@ -178,14 +170,12 @@ def run_locate_sweep(
 
     rows = []
     for op, samples in (("lookup", look), ("insert", ins)):
-        base = {}
-        for name, strat, s, decomp in variants:
-            ts = sorted(samples[name])
-            dt = ts[len(ts) // 2]
-            if decomp != "percall":
-                base.setdefault(s, {})[strat] = dt
-        for name, strat, s, decomp in variants:
-            dt = sorted(samples[name])[len(samples[name]) // 2]
+        med = {
+            name: sorted(samples[name])[len(samples[name]) // 2]
+            for name, _, _ in variants
+        }
+        for name, strat, s in variants:
+            dt = med[name]
             rows.append(
                 {
                     "name": f"{op}/{name}",
@@ -194,11 +184,10 @@ def run_locate_sweep(
                     "mops": batch / dt / 1e6,
                     "op": op,
                     "strategy": strat,
-                    "decomposition": decomp,
                     "n_shards": s,
                     "batch": batch,
                     "speedup_vs_binsearch": round(
-                        base[s]["binsearch"] / dt, 3
+                        med[f"binsearch/S={s}"] / dt, 3
                     ),
                 }
             )

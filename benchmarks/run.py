@@ -17,9 +17,8 @@ Sections:
   gateway            — async request gateway: closed-loop tail latency vs
                        offered load, batched vs batch-size-1 passthrough
                        (ISSUE 7 acceptance)
-  locate_sweep       — binsearch/spline/fused locate strategies, fused
-                       under persistent vs per-call (hi, lo) key
-                       decomposition (ISSUE 8 acceptance)
+  locate_sweep       — binsearch/spline/fused locate strategies at
+                       S = 1 and S = 4
   pipeline_index     — UpLIF as the framework's doc index
   kernels            — Pallas kernel micro (interpret mode)
 """

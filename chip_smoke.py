@@ -176,9 +176,9 @@ def phase_kernels(n_keys: int = 200_000, seed: int = 0, rounds: int = 4,
 
     check_strategies()
 
-    state, _, jb, codes, static = fused._read_view()
+    state, _, jb, static = fused._read_view()
     q = jnp.asarray(keys[:batch])
-    text = fops.slookup.lower(state, q, jb, codes, static=static).as_text()
+    text = fops.slookup.lower(state, q, jb, static=static).as_text()
     compiled = "tpu_custom_call" in text
     assert compiled != kops.interpret_mode(), (
         "fused lookup lowered to the wrong kernel form"

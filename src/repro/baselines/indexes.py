@@ -23,6 +23,8 @@ workloads.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 
 from repro.core.gmm import init_gmm_uniform
@@ -34,12 +36,12 @@ class BTreeLike(UpLIF):
     """STX-B+Tree stand-in: no learned model, uniform node slack.
 
     The model-free traversal (full binary search over the slot array,
-    log2(capacity) dependent probes) is selected through the functional
-    core's static locate strategy — see repro/core/fops.py."""
-
-    LOCATE = LOCATE_BINSEARCH
+    log2(capacity) dependent probes) is the functional core's binsearch
+    locate strategy, which this class sets on its config — see
+    repro/core/fops.py."""
 
     def __init__(self, keys, vals=None, config: UpLIFConfig = UpLIFConfig()):
+        config = dataclasses.replace(config, locate=LOCATE_BINSEARCH)
         gmm = init_gmm_uniform(
             float(np.min(keys)) if len(keys) else 0.0,
             float(np.max(keys)) if len(keys) else 1.0,

@@ -100,18 +100,14 @@ def _require_fusable(ok: bool):
         )
 
 
-def _locate(static: UpLIFStatic, slot_keys, model, queries, halves=None):
+def _locate(static: UpLIFStatic, slot_keys, model, queries):
     """(j, ins_cap): j = index of the last slot with key <= q (-1 if below
     all keys); ins_cap = largest slot index an insert derived from this
     locate may target. For the exact binsearch ins_cap is just cap-1; for
     the bounded learned search it is the end of the searched span, so a
     boundary the span could not prove stays UNPLACED (fails the window
     accept, overflows to the BMAT) instead of landing outside the rows
-    future lookups will search.
-
-    ``halves`` is the state's persistent (hi, lo) decomposition (or None):
-    the fused branch consumes it directly so the kernel adapter skips the
-    per-call O(cap) int64 split; the jnp branches ignore it."""
+    future lookups will search."""
     cap = slot_keys.shape[0]
     if static.locate == LOCATE_BINSEARCH:
         # B+Tree analogue: full bisect, log2(capacity) dependent probes.
@@ -142,11 +138,6 @@ def _locate(static: UpLIFStatic, slot_keys, model, queries, halves=None):
             n_table=model.table.shape[0],
             n_knots=model.spline_keys.shape[0],
             cap=cap, window=static.window, rs_iters=static.rs_iters,
-            spline_hi=None if halves is None else halves.spline_hi,
-            spline_lo=None if halves is None else halves.spline_lo,
-            spline_pos32=None if halves is None else halves.spline_pos32,
-            slot_hi=None if halves is None else halves.slot_hi,
-            slot_lo=None if halves is None else halves.slot_lo,
         )
 
     # Learned path: spline predict + bounded probes over the 3-row span
@@ -194,7 +185,7 @@ def _probe(slot_keys, slot_vals, slot_occ, j, queries):
 # ---------------------------------------------------------------------------
 
 
-def _bmat_rank(static: UpLIFStatic, bmat: BMATState, queries, halves=None):
+def _bmat_rank(static: UpLIFStatic, bmat: BMATState, queries):
     """searchsorted-left rank over the packed BMAT (layout per static)."""
     cap = bmat.keys.shape[0]
     if static.locate == LOCATE_FUSED:
@@ -207,10 +198,6 @@ def _bmat_rank(static: UpLIFStatic, bmat: BMATState, queries, halves=None):
             bmat.keys, bmat.fences, queries,
             jnp.zeros(queries.shape, dtype=jnp.int64),
             cap=cap, nf=bmat.fences.shape[0], fanout=static.fanout,
-            keys_hi=None if halves is None else halves.bmat_hi,
-            keys_lo=None if halves is None else halves.bmat_lo,
-            fences_hi=None if halves is None else halves.fence_hi,
-            fences_lo=None if halves is None else halves.fence_lo,
         )
     if static.bmat_kind == RBMAT:
         return _rank_rbmat(bmat.keys, queries, max(1, int(np.log2(cap))))
@@ -244,13 +231,11 @@ def _bmat_probe(bmat: BMATState, ranks, queries):
 def lookup(state: UpLIFState, queries, *, static: UpLIFStatic):
     """Batched point lookup -> (found bool[n], values int64[n]). Pure: the
     state is read-only, so lookups never force a state swap."""
-    j, _ = _locate(
-        static, state.slots.keys, state.model, queries, halves=state.halves
-    )
+    j, _ = _locate(static, state.slots.keys, state.model, queries)
     _, alive, vals, _ = _probe(
         state.slots.keys, state.slots.vals, state.slots.occ, j, queries
     )
-    ranks = _bmat_rank(static, state.bmat, queries, halves=state.halves)
+    ranks = _bmat_rank(static, state.bmat, queries)
     _, b_alive, b_vals, _ = _bmat_probe(state.bmat, ranks, queries)
     b_alive = b_alive & ~alive
     return alive | b_alive, jnp.where(b_alive, b_vals, vals)
@@ -272,19 +257,14 @@ def _dedup_last_wins(keys):
 
 def _inplace_window_insert(
     slot_keys, slot_vals, slot_occ, q_keys, q_vals, starts, accept, valid,
-    window: int, movement_k: int, slot_halves=None,
+    window: int, movement_k: int,
 ):
     """One vectorized round of conflict-free in-place window inserts.
 
     ``starts`` are sorted grid-aligned window starts; ``accept`` marks the
     per-grid-segment representative (disjoint by construction). Returns the
-    updated slot arrays, the success mask, the min key-span of failed
-    windows (granularity measure S2) and the maintained ``slot_halves``
-    ((hi, lo) of ``slot_keys``, or None): the touched rows' halves are
-    refreshed by splitting only the Q accepted windows (O(Q·W)) and
-    gathering through the same window->row map as the int64 writeback, so
-    the persistent decomposition stays byte-identical without an O(cap)
-    re-split.
+    updated slot arrays, the success mask and the min key-span of failed
+    windows (granularity measure S2).
     """
     cap = slot_keys.shape[0]
     W = window
@@ -374,32 +354,21 @@ def _inplace_window_insert(
     slot_keys = jnp.where(has, n_k[rr], slot_keys.reshape(nw, W)).reshape(cap)
     slot_vals = jnp.where(has, n_v[rr], slot_vals.reshape(nw, W)).reshape(cap)
     slot_occ = jnp.where(has, n_o[rr], slot_occ.reshape(nw, W)).reshape(cap)
-    if slot_halves is not None:
-        sl_hi, sl_lo = slot_halves
-        nk_hi, nk_lo = kops.split_key(n_k)
-        sl_hi = jnp.where(has, nk_hi[rr], sl_hi.reshape(nw, W)).reshape(cap)
-        sl_lo = jnp.where(has, nk_lo[rr], sl_lo.reshape(nw, W)).reshape(cap)
-        slot_halves = (sl_hi, sl_lo)
 
     span = w_k[:, W - 1] - w_k[:, 0]
     failed_span = jnp.where(
         accept & ~can & valid, span, jnp.asarray(_I64_MAX)
     )
-    return slot_keys, slot_vals, slot_occ, can, failed_span, slot_halves
+    return slot_keys, slot_vals, slot_occ, can, failed_span
 
 
-def _merge_pending(static, bmat: BMATState, keys, vals, pending, n_bmat_live,
-                   halves=None):
+def _merge_pending(static, bmat: BMATState, keys, vals, pending, n_bmat_live):
     """Route the still-pending batch into the BMAT arrays (value updates for
     keys already buffered — incl. tombstone revival — sorted merge for fresh
-    ones). The caller must guarantee capacity >= size + |pending| + 1.
-    Returns the refreshed (bmat_hi, bmat_lo, fence_hi, fence_lo) halves as
-    the last element (None when ``halves`` is None): the merge rewrites the
-    whole packed array anyway, so re-splitting its output is proportional
-    work, unlike the per-lookup re-split this pays off."""
+    ones). The caller must guarantee capacity >= size + |pending| + 1."""
     bcap = bmat.keys.shape[0]
     qk = jnp.where(pending, keys, KEY_MAX)
-    ranks = _bmat_rank(static, bmat, qk, halves=halves)
+    ranks = _bmat_rank(static, bmat, qk)
     idx = jnp.minimum(ranks.astype(jnp.int64), bcap - 1)
     present = (bmat.keys[idx] == qk) & pending
     revived = jnp.sum(present & (bmat.vals[idx] == TOMBSTONE))
@@ -415,27 +384,23 @@ def _merge_pending(static, bmat: BMATState, keys, vals, pending, n_bmat_live,
     keys2, vals2, size2 = _merge(
         bmat.keys, new_vals, bmat.size, mk, mv, n_new.astype(jnp.int32)
     )
-    fences2 = _make_fences(keys2, static.fanout)
     out = BMATState(
         keys=keys2,
         vals=vals2,
-        fences=fences2,
+        fences=_make_fences(keys2, static.fanout),
         size=size2,
     )
-    bmat_halves = None
-    if halves is not None:
-        bmat_halves = kops.split_key(keys2) + kops.split_key(fences2)
-    return out, n_bmat_live + revived + n_new, jnp.sum(pending), bmat_halves
+    return out, n_bmat_live + revived + n_new, jnp.sum(pending)
 
 
-def _next_locate(i, rounds, locate, sk, slot_halves, pending, j, icap):
+def _next_locate(i, rounds, locate, sk, pending, j, icap):
     """(j, ins_cap) for insert round ``i + 1``: a fresh locate over the
     slots round ``i`` rewrote, skipped after the last round."""
     if rounds == 1:
         return j, icap
     return jax.lax.cond(
         i + 1 < rounds,
-        lambda: locate(sk, slot_halves, pending)[1:],
+        lambda: locate(sk, pending)[1:],
         lambda: (j, icap),
     )
 
@@ -471,10 +436,6 @@ def insert(
     sk, sv, so = state.slots
     bmat = state.bmat
     c = state.counters
-    halves = state.halves
-    slot_halves = (
-        None if halves is None else (halves.slot_hi, halves.slot_lo)
-    )
     cap = sk.shape[0]
     assert cap % W == 0, "slot capacity must be W-aligned (nullifier align)"
     n = keys.shape[0]
@@ -482,23 +443,20 @@ def insert(
     pending = (keys != KEY_MAX) & ~_dedup_last_wins(keys)
     n_keys, n_bmat_live = c.n_keys, c.n_bmat_live
 
-    def locate(sk, slot_halves, pending):
-        h = halves
-        if h is not None:
-            h = h._replace(slot_hi=slot_halves[0], slot_lo=slot_halves[1])
+    def locate(sk, pending):
         qk = jnp.where(pending, keys, KEY_MAX)
-        j, icap = _locate(static, sk, state.model, qk, halves=h)
+        j, icap = _locate(static, sk, state.model, qk)
         return qk, j, icap
 
     # upsert keys already in the slot array (revives tombstones)
-    qk, j, icap = locate(sk, slot_halves, pending)
+    qk, j, icap = locate(sk, pending)
     hit, alive, _, jj = _probe(sk, sv, so, j, qk)
     n_keys = n_keys + jnp.sum(hit & ~alive)
     sv = sv.at[jnp.where(hit, jj, cap + 1)].set(vals, mode="drop")
     pending = pending & ~hit
     if check_bmat:
         # keys live in the BMAT -> value update there
-        ranks = _bmat_rank(static, bmat, qk, halves=halves)
+        ranks = _bmat_rank(static, bmat, qk)
         _, b_alive, _, bidx = _bmat_probe(bmat, ranks, qk)
         upd = b_alive & pending
         bcap = bmat.keys.shape[0]
@@ -517,8 +475,7 @@ def insert(
         round. Round 0 reuses the probe's locate (the slot keys have not
         moved since); each round locates for the next one, except the
         last, so a wave runs ``rounds`` locates in all."""
-        (sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran,
-         j, icap) = carry
+        sk, sv, so, pending, n_keys, n_inplace, min_gran, j, icap = carry
         qk = jnp.where(pending, keys, KEY_MAX)
         # clamp to the locate span so a boundary the bounded search could
         # not prove lands in the BMAT, never outside the searched rows
@@ -534,40 +491,30 @@ def insert(
         )
         accept = pend_s & first
         starts = jnp.clip(bs * W, 0, cap - W)
-        sk, sv, so, can, failed_span, slot_halves = _inplace_window_insert(
-            sk, sv, so, qs, vs, starts, accept, pend_s,
-            W, static.movement_k, slot_halves=slot_halves,
+        sk, sv, so, can, failed_span = _inplace_window_insert(
+            sk, sv, so, qs, vs, starts, accept, pend_s, W, static.movement_k,
         )
         ok = can & pend_s
         n_ok = jnp.sum(ok)
         pending = pending & ~jnp.zeros(n, dtype=bool).at[order].set(ok)
-        j, icap = _next_locate(i, rounds, locate, sk, slot_halves, pending,
-                               j, icap)
-        return (sk, sv, so, slot_halves, pending, n_keys + n_ok,
+        j, icap = _next_locate(i, rounds, locate, sk, pending, j, icap)
+        return (sk, sv, so, pending, n_keys + n_ok,
                 n_inplace + n_ok, jnp.minimum(min_gran, jnp.min(failed_span)),
                 j, icap)
 
-    sk, sv, so, slot_halves, pending, n_keys, n_inplace, min_gran, _, _ = (
+    sk, sv, so, pending, n_keys, n_inplace, min_gran, _, _ = (
         jax.lax.fori_loop(
             0, rounds, accept_round,
-            (sk, sv, so, slot_halves, pending, n_keys, c.n_inplace,
-             c.min_granularity, j, icap),
+            (sk, sv, so, pending, n_keys, c.n_inplace, c.min_granularity,
+             j, icap),
         )
     )
 
-    if halves is not None:
-        halves = halves._replace(
-            slot_hi=slot_halves[0], slot_lo=slot_halves[1]
-        )
     n_over = jnp.asarray(0, dtype=jnp.int64)
     if merge_overflow:
-        bmat, n_bmat_live, n_over, bh = _merge_pending(
-            static, bmat, keys, vals, pending, n_bmat_live, halves=halves
+        bmat, n_bmat_live, n_over = _merge_pending(
+            static, bmat, keys, vals, pending, n_bmat_live
         )
-        if halves is not None:
-            halves = halves._replace(
-                bmat_hi=bh[0], bmat_lo=bh[1], fence_hi=bh[2], fence_lo=bh[3]
-            )
 
     counters = Counters(
         n_keys=n_keys,
@@ -581,7 +528,6 @@ def insert(
         model=state.model,
         bmat=bmat,
         counters=counters,
-        halves=halves,
     )
     return new_state, InsertResult(
         pending=pending, n_overflow=n_over, n_round_keys=n_round_keys,
@@ -604,12 +550,12 @@ def delete(state: UpLIFState, keys, *, static: UpLIFStatic):
     cap = sk.shape[0]
     canonical = ~_dedup_last_wins(keys)
 
-    j, _ = _locate(static, sk, state.model, keys, halves=state.halves)
+    j, _ = _locate(static, sk, state.model, keys)
     _, alive, _, jj = _probe(sk, sv, so, j, keys)
     once = alive & canonical
     sv = sv.at[jnp.where(once, jj, cap + 1)].set(TOMBSTONE, mode="drop")
 
-    ranks = _bmat_rank(static, bmat, keys, halves=state.halves)
+    ranks = _bmat_rank(static, bmat, keys)
     _, b_alive, _, bidx = _bmat_probe(bmat, ranks, keys)
     b_alive = b_alive & ~alive
     b_once = b_alive & canonical
@@ -628,7 +574,6 @@ def delete(state: UpLIFState, keys, *, static: UpLIFStatic):
         model=state.model,
         bmat=bmat._replace(vals=bvals),
         counters=counters,
-        halves=state.halves,  # tombstones touch vals only: halves unchanged
     )
     return new_state, alive | b_alive
 
@@ -660,7 +605,7 @@ def range_scan(
     L = min(4 * max_out, cap)
     lim = max_out if lim is None else jnp.minimum(lim, max_out)
 
-    j, _ = _locate(static, sk, state.model, lo, halves=state.halves)
+    j, _ = _locate(static, sk, state.model, lo)
     jj = jnp.clip(j, 0, cap - 1)
     s = jnp.where((j >= 0) & (sk[jj] == lo), jj, j + 1)
     s = jnp.clip(s, 0, cap - L)
@@ -695,10 +640,8 @@ def range_scan(
     bcap = bmat.keys.shape[0]
     M = min(max_out, bcap)
     hi_safe = jnp.minimum(hi, KEY_MAX - 1)
-    r0 = _bmat_rank(static, bmat, lo, halves=state.halves).astype(jnp.int64)
-    r1 = _bmat_rank(
-        static, bmat, hi_safe + 1, halves=state.halves
-    ).astype(jnp.int64)
+    r0 = _bmat_rank(static, bmat, lo).astype(jnp.int64)
+    r1 = _bmat_rank(static, bmat, hi_safe + 1).astype(jnp.int64)
     b_start = jnp.clip(r0, 0, bcap - M)
     b_end = b_start + M
     b_front = jnp.where(
@@ -764,40 +707,13 @@ def range_scan(
 # ---------------------------------------------------------------------------
 
 
-def _locate_stacked(static: UpLIFStatic, slot_keys, model, q, sid,
-                    halves=None, codes=None):
+def _locate_stacked(static: UpLIFStatic, slot_keys, model, q, sid):
     """Shard-local (j, ins_cap) of the last slot of shard ``sid`` with
     key <= q (same contract as ``_locate``).
 
-    ``slot_keys`` is [S, cap]; ``q``/``sid`` are flat [N].
-
-    Per-shard dispatch: when ``static.locate`` is a TUPLE of distinct
-    strategies, ``codes`` (traced int32[S], indices into the tuple) assigns
-    each shard its strategy. The wave runs once per distinct strategy —
-    at most 3 launches, each a full-batch program identical to a uniform
-    wave — and every query keeps the (j, ins_cap) pair of its own shard's
-    branch, so the locate span (and with it the insert clamp) matches what
-    a uniform run of that strategy would produce. The tuple is sorted and
-    deduplicated by the router, so at most 7 static values exist
-    (3 singles are plain strings; 3 pairs + 1 triple) and the jit cache
-    stays flat no matter how the controller flips shards.
+    ``slot_keys`` is [S, cap]; ``q``/``sid`` are flat [N]. Every shard runs
+    ``static.locate``, so the wave is one full-batch program.
     """
-    if isinstance(static.locate, tuple):
-        sel = codes[sid]
-        j = icap = None
-        for i, strat in enumerate(static.locate):
-            ji, ici = _locate_stacked(
-                static._replace(locate=strat), slot_keys, model, q, sid,
-                halves=halves,
-            )
-            if j is None:
-                j, icap = ji, ici
-            else:
-                m = sel == i
-                j = jnp.where(m, ji, j)
-                icap = jnp.where(m, ici, icap)
-        return j, icap
-
     S, cap = slot_keys.shape
     flat = slot_keys.reshape(-1)
     base = sid * cap
@@ -830,14 +746,6 @@ def _locate_stacked(static: UpLIFStatic, slot_keys, model, q, sid,
             n_table=model.table.shape[1],
             n_knots=model.spline_keys.shape[1],
             cap=cap, window=static.window, rs_iters=static.rs_iters,
-            spline_hi=None if halves is None
-            else halves.spline_hi.reshape(-1),
-            spline_lo=None if halves is None
-            else halves.spline_lo.reshape(-1),
-            spline_pos32=None if halves is None
-            else halves.spline_pos32.reshape(-1),
-            slot_hi=None if halves is None else halves.slot_hi.reshape(-1),
-            slot_lo=None if halves is None else halves.slot_lo.reshape(-1),
         )
 
     W = static.window
@@ -903,29 +811,8 @@ def _probe_stacked(slots: SlotsState, j, q, sid):
     return hit, alive, jnp.where(alive, vv, 0), jnp.clip(j, 0, cap - 1)
 
 
-def _bmat_rank_stacked(static: UpLIFStatic, bmat: BMATState, q, sid,
-                       halves=None, codes=None):
-    """Shard-local searchsorted-left rank; q/sid are flat [N].
-
-    Mixed per-shard strategies collapse to AT MOST two launches here: the
-    rank is an exact integer search whose jnp program depends only on
-    ``bmat_kind`` (spline and binsearch shards share it bit-for-bit), so
-    only a fused-vs-jnp partition of the batch remains.
-    """
-    if isinstance(static.locate, tuple):
-        rj = _bmat_rank_stacked(
-            static._replace(locate=LOCATE_BINSEARCH), bmat, q, sid,
-            halves=halves,
-        )
-        if LOCATE_FUSED not in static.locate:
-            return rj
-        rf = _bmat_rank_stacked(
-            static._replace(locate=LOCATE_FUSED), bmat, q, sid,
-            halves=halves,
-        )
-        sel = codes[sid]
-        return jnp.where(sel == static.locate.index(LOCATE_FUSED), rf, rj)
-
+def _bmat_rank_stacked(static: UpLIFStatic, bmat: BMATState, q, sid):
+    """Shard-local searchsorted-left rank; q/sid are flat [N]."""
     S, cap = bmat.keys.shape
     kflat = bmat.keys.reshape(-1)
     base = sid * cap
@@ -934,12 +821,6 @@ def _bmat_rank_stacked(static: UpLIFStatic, bmat: BMATState, q, sid,
         return kops.bmat_rank_fused(
             kflat, bmat.fences.reshape(-1), q, sid,
             cap=cap, nf=bmat.fences.shape[1], fanout=static.fanout,
-            keys_hi=None if halves is None else halves.bmat_hi.reshape(-1),
-            keys_lo=None if halves is None else halves.bmat_lo.reshape(-1),
-            fences_hi=None if halves is None
-            else halves.fence_hi.reshape(-1),
-            fences_lo=None if halves is None
-            else halves.fence_lo.reshape(-1),
         ).astype(jnp.int64)
     if static.bmat_kind == RBMAT:
         levels = max(1, int(np.log2(cap)))
@@ -1014,40 +895,28 @@ def _route_on_device(boundaries, q):
 
 
 @functools.partial(jax.jit, static_argnames=("static",))
-def slookup(state: UpLIFState, q, boundaries, codes=None, *,
-            static: UpLIFStatic):
-    """Stacked lookup: state leaves are [S, ...]; q is flat [N].
-    ``codes`` is the per-shard strategy index (None unless ``static.locate``
-    is a mixed tuple — see ``_locate_stacked``)."""
+def slookup(state: UpLIFState, q, boundaries, *, static: UpLIFStatic):
+    """Stacked lookup: state leaves are [S, ...]; q is flat [N]."""
     sid = _route_on_device(boundaries, q)
     # named phases group the device operations in a profiler trace
     with jax.named_scope("locate"):
-        j, _ = _locate_stacked(
-            static, state.slots.keys, state.model, q, sid,
-            halves=state.halves, codes=codes,
-        )
+        j, _ = _locate_stacked(static, state.slots.keys, state.model, q, sid)
         _, alive, vals, _ = _probe_stacked(state.slots, j, q, sid)
     with jax.named_scope("rank"):
-        ranks = _bmat_rank_stacked(
-            static, state.bmat, q, sid, halves=state.halves, codes=codes
-        )
+        ranks = _bmat_rank_stacked(static, state.bmat, q, sid)
         _, b_alive, b_vals, _ = _bmat_probe_stacked(state.bmat, ranks, q, sid)
     b_alive = b_alive & ~alive
     return alive | b_alive, jnp.where(b_alive, b_vals, vals)
 
 
 @functools.partial(jax.jit, static_argnames=("static",))
-def sdelete(state: UpLIFState, q, boundaries, codes=None, *,
-            static: UpLIFStatic):
+def sdelete(state: UpLIFState, q, boundaries, *, static: UpLIFStatic):
     """Stacked tombstone delete -> (state, hit [N])."""
     S, cap = state.slots.keys.shape
     sid = _route_on_device(boundaries, q)
     canonical = ~_dedup_last_wins(q)
 
-    j, _ = _locate_stacked(
-        static, state.slots.keys, state.model, q, sid,
-        halves=state.halves, codes=codes,
-    )
+    j, _ = _locate_stacked(static, state.slots.keys, state.model, q, sid)
     _, alive, _, jj = _probe_stacked(state.slots, j, q, sid)
     once = alive & canonical
     sv = state.slots.vals.reshape(-1).at[
@@ -1055,9 +924,7 @@ def sdelete(state: UpLIFState, q, boundaries, codes=None, *,
     ].set(TOMBSTONE, mode="drop").reshape(S, cap)
 
     bcap = state.bmat.keys.shape[1]
-    ranks = _bmat_rank_stacked(
-        static, state.bmat, q, sid, halves=state.halves, codes=codes
-    )
+    ranks = _bmat_rank_stacked(static, state.bmat, q, sid)
     _, b_alive, _, bidx = _bmat_probe_stacked(state.bmat, ranks, q, sid)
     b_alive = b_alive & ~alive
     b_once = b_alive & canonical
@@ -1079,30 +946,22 @@ def sdelete(state: UpLIFState, q, boundaries, codes=None, *,
 
 
 @functools.partial(jax.jit, static_argnames=("static",))
-def srank(state: UpLIFState, q, boundaries, codes=None, *,
-          static: UpLIFStatic):
+def srank(state: UpLIFState, q, boundaries, *, static: UpLIFStatic):
     """Stacked shard-local adjusted rank (O(cap) reduce — API/tests only)."""
     sid = _route_on_device(boundaries, q)
     live = state.slots.occ & (state.slots.vals != TOMBSTONE)
     keys_q = state.slots.keys[sid]   # [N, cap] batched gather (cold path)
     live_q = live[sid]
     arr_rank = jnp.sum(live_q & (keys_q < q[:, None]), axis=1)
-    return arr_rank + _bmat_rank_stacked(
-        static, state.bmat, q, sid, halves=state.halves, codes=codes
-    )
+    return arr_rank + _bmat_rank_stacked(static, state.bmat, q, sid)
 
 
 def _merge_pending_stacked(static, bmat: BMATState, keys, vals, pending, sid,
-                           n_bmat_live, halves=None, codes=None):
-    """Segmented (per-shard) BMAT merge over the flat [S*bcap] view.
-    Returns refreshed (bmat_hi, bmat_lo, fence_hi, fence_lo) halves last
-    (None when ``halves`` is None) — the merge rewrites the packed arrays,
-    so splitting its output is proportional work done once per batch."""
+                           n_bmat_live):
+    """Segmented (per-shard) BMAT merge over the flat [S*bcap] view."""
     S, bcap = bmat.keys.shape
     qk = jnp.where(pending, keys, KEY_MAX)
-    ranks = _bmat_rank_stacked(
-        static, bmat, qk, sid, halves=halves, codes=codes
-    )
+    ranks = _bmat_rank_stacked(static, bmat, qk, sid)
     present, _, _, idx = _bmat_probe_stacked(bmat, ranks, qk, sid)
     present = present & pending
     bv_flat = bmat.vals.reshape(-1)
@@ -1123,9 +982,7 @@ def _merge_pending_stacked(static, bmat: BMATState, keys, vals, pending, sid,
     mv = jnp.where(fresh, vals, 0)[order]
     fr = fresh[order]
     sid_s = jnp.where(fr, sid[order], 0)
-    r2 = _bmat_rank_stacked(
-        static, bmat, mk, sid_s, halves=halves, codes=codes
-    )
+    r2 = _bmat_rank_stacked(static, bmat, mk, sid_s)
     g_idx = jnp.cumsum(fr) - 1               # global index among fresh
     within = g_idx - shard_start[sid_s]
     new_pos = r2 + within
@@ -1152,21 +1009,14 @@ def _merge_pending_stacked(static, bmat: BMATState, keys, vals, pending, sid,
         jnp.where(from_old, bmat.keys.reshape(-1)[g], KEY_MAX),
     )
     out_vals = jnp.where(is_new, mv[pick], jnp.where(from_old, new_vals[g], 0))
-    out_fences = _make_fences_stacked(out_keys, static.fanout)
     out = BMATState(
         keys=out_keys,
         vals=out_vals,
-        fences=out_fences,
+        fences=_make_fences_stacked(out_keys, static.fanout),
         size=bmat.size + cnt.astype(bmat.size.dtype),
     )
-    bmat_halves = None
-    if halves is not None:
-        bmat_halves = kops.split_key(out_keys) + kops.split_key(out_fences)
     n_over = _seg_add(S, sid, pending)
-    return (
-        out, n_bmat_live + _seg_add(S, sid, revived) + cnt, n_over,
-        bmat_halves,
-    )
+    return out, n_bmat_live + _seg_add(S, sid, revived) + cnt, n_over
 
 
 def _make_fences_stacked(keys, fanout: int):
@@ -1178,7 +1028,7 @@ def _make_fences_stacked(keys, fanout: int):
 
 
 @functools.partial(jax.jit, static_argnames=("static",))
-def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
+def sinsert(state: UpLIFState, keys, vals, boundaries, *,
             static: UpLIFStatic):
     """Stacked upsert: keys/vals/sid are flat [N]. One flat program — the
     grid windows of all shards tile the concatenated slot array (per-shard
@@ -1196,35 +1046,21 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
     so = state.slots.occ.reshape(-1)
     bmat = state.bmat
     c = state.counters
-    halves = state.halves
-    # the in-loop window writeback runs on the flat [S*cap] view, so the
-    # slot halves travel flat too; reshaped back to [S, cap] at the end
-    slot_halves = (
-        None if halves is None
-        else (halves.slot_hi.reshape(-1), halves.slot_lo.reshape(-1))
-    )
 
     pending = (keys != KEY_MAX) & ~_dedup_last_wins(keys)
     n_keys, n_bmat_live = c.n_keys, c.n_bmat_live
 
-    def locate(sk, slot_halves, pending):
-        h = halves
-        if h is not None:
-            h = h._replace(
-                slot_hi=slot_halves[0].reshape(S, cap),
-                slot_lo=slot_halves[1].reshape(S, cap),
-            )
+    def locate(sk, pending):
         qk = jnp.where(pending, keys, KEY_MAX)
         j, icap = _locate_stacked(
-            static, sk.reshape(S, cap), state.model, qk, sid,
-            halves=h, codes=codes,
+            static, sk.reshape(S, cap), state.model, qk, sid
         )
         return qk, j, icap
 
     # ---- upsert keys already in the slot array or live in the BMAT ------
     # (named phases group the device operations in a profiler trace)
     with jax.named_scope("probe"):
-        qk, j, icap = locate(sk, slot_halves, pending)
+        qk, j, icap = locate(sk, pending)
         slots2 = SlotsState(
             keys=sk.reshape(S, cap), vals=sv.reshape(S, cap),
             occ=so.reshape(S, cap),
@@ -1234,9 +1070,7 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
         sv = sv.at[jnp.where(hit, sid * cap + jj, S * cap + 1)].set(
             vals, mode="drop"
         )
-        ranks = _bmat_rank_stacked(
-            static, bmat, qk, sid, halves=halves, codes=codes
-        )
+        ranks = _bmat_rank_stacked(static, bmat, qk, sid)
         _, b_alive, _, bidx = _bmat_probe_stacked(bmat, ranks, qk, sid)
         upd = b_alive & pending
         bcap = bmat.keys.shape[1]
@@ -1255,12 +1089,12 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
         round once instead of once per round. The probe's locate serves
         round 0; a later round first locates over the slots the round
         before it rewrote."""
-        (i, _, sk, sv, so, slot_halves, pending, n_keys, n_inplace,
-         min_gran, j, icap) = carry
+        (i, _, sk, sv, so, pending, n_keys, n_inplace, min_gran, j,
+         icap) = carry
         if rounds > 1:
             j, icap = jax.lax.cond(
                 i > 0,
-                lambda: locate(sk, slot_halves, pending)[1:],
+                lambda: locate(sk, pending)[1:],
                 lambda: (j, icap),
             )
         qk = jnp.where(pending, keys, KEY_MAX)
@@ -1276,9 +1110,8 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
         first = jnp.concatenate([jnp.ones(1, dtype=bool), bs[1:] != bs[:-1]])
         accept = ps & first
         starts = jnp.clip(bs * W, 0, S * cap - W)
-        sk, sv, so, can, failed_span, slot_halves = _inplace_window_insert(
+        sk, sv, so, can, failed_span = _inplace_window_insert(
             sk, sv, so, qs, vs, starts, accept, ps, W, static.movement_k,
-            slot_halves=slot_halves,
         )
         ok = can & ps
         sid_w = jnp.clip(bs // nw_per, 0, S - 1)
@@ -1287,38 +1120,28 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
             jnp.where(failed_span < _I64_MAX, sid_w, S)
         ].min(failed_span, mode="drop")
         pending = pending & ~jnp.zeros(N, dtype=bool).at[order].set(ok)
-        return (i + 1, jnp.sum(ok, dtype=jnp.int32), sk, sv, so, slot_halves,
-                pending, n_keys + ok_per, n_inplace + ok_per,
+        return (i + 1, jnp.sum(ok, dtype=jnp.int32), sk, sv, so, pending,
+                n_keys + ok_per, n_inplace + ok_per,
                 jnp.minimum(min_gran, span_per), j, icap)
 
     def can_place(carry):
         """Run a round while a key is pending and the round before placed
         one: a round that places none leaves the slots, ``pending`` and
         the counters as they were, so every later round would repeat it."""
-        i, n_placed, pending = carry[0], carry[1], carry[6]
+        i, n_placed, pending = carry[0], carry[1], carry[5]
         return (i < rounds) & jnp.any(pending) & ((i == 0) | (n_placed > 0))
 
     with jax.named_scope("rounds"):
-        (n_rounds, _, sk, sv, so, slot_halves, pending, n_keys, n_inplace,
-         min_gran, _, _) = jax.lax.while_loop(
+        (n_rounds, _, sk, sv, so, pending, n_keys, n_inplace, min_gran, _,
+         _) = jax.lax.while_loop(
             can_place, accept_round,
-            (jnp.int32(0), jnp.int32(0), sk, sv, so, slot_halves, pending,
-             n_keys, c.n_inplace, c.min_granularity, j, icap),
+            (jnp.int32(0), jnp.int32(0), sk, sv, so, pending, n_keys,
+             c.n_inplace, c.min_granularity, j, icap),
         )
 
-    if halves is not None:
-        halves = halves._replace(
-            slot_hi=slot_halves[0].reshape(S, cap),
-            slot_lo=slot_halves[1].reshape(S, cap),
-        )
     with jax.named_scope("merge"):
-        bmat, n_bmat_live, n_over, bh = _merge_pending_stacked(
-            static, bmat, keys, vals, pending, sid, n_bmat_live,
-            halves=halves, codes=codes,
-        )
-    if halves is not None:
-        halves = halves._replace(
-            bmat_hi=bh[0], bmat_lo=bh[1], fence_hi=bh[2], fence_lo=bh[3]
+        bmat, n_bmat_live, n_over = _merge_pending_stacked(
+            static, bmat, keys, vals, pending, sid, n_bmat_live
         )
     counters = Counters(
         n_keys=n_keys,
@@ -1335,7 +1158,6 @@ def sinsert(state: UpLIFState, keys, vals, boundaries, codes=None, *,
         model=state.model,
         bmat=bmat,
         counters=counters,
-        halves=halves,
     )
     return new_state, InsertResult(
         pending=pending, n_overflow=jnp.sum(n_over),
@@ -1356,6 +1178,4 @@ def adjusted_rank(state: UpLIFState, queries, *, static: UpLIFStatic):
     arr_rank = jnp.sum(
         live[None, :] & (sk[None, :] < queries[:, None]), axis=1
     )
-    return arr_rank + _bmat_rank(
-        static, state.bmat, queries, halves=state.halves
-    ).astype(jnp.int64)
+    return arr_rank + _bmat_rank(static, state.bmat, queries).astype(jnp.int64)

@@ -48,7 +48,6 @@ import contextlib
 import dataclasses
 import functools
 import threading
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -59,17 +58,9 @@ from repro import obs
 from repro.core import fops
 from repro.core.bmat import BMAT, BPMAT, RBMAT, _make_fences, bmat_height
 from repro.shapes import grow_capacity, pow2_at_least
-from repro.core.state import (
-    LOCATE_FUSED,
-    LOCATE_STRATEGIES,
-    UpLIFState,
-    UpLIFStatic,
-    make_halves,
-    resolve_locate,
-)
+from repro.core.state import UpLIFState, UpLIFStatic, resolve_locate
 from repro.core.types import BMATState, GMMState, KEY_MAX, SlotsState
 from repro.core.uplif import UpLIF, UpLIFConfig, bucket_width, join_pieces
-from repro.kernels.ops import split_key
 
 
 # --------------------------------------------------------------------------
@@ -81,38 +72,28 @@ from repro.kernels.ops import split_key
 # --------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("statics", "max_out"))
-def _vrange(state, lo, hi, lim, *, statics, max_out):
-    """Per-shard range scans, unrolled in one program. ``statics`` is a
-    length-S tuple so each shard's scan runs under its OWN locate strategy
-    (the per-shard dispatch axis); uniform routers pass S identical
-    entries, which hash to the same jit variant as before. Variant growth
-    is bounded by the distinct strategy assignments actually used — the
-    controller flips a shard's strategy rarely (it is a learned action),
-    and results are byte-identical across strategies regardless."""
+@functools.partial(jax.jit, static_argnames=("static", "max_out"))
+def _vrange(state, lo, hi, lim, *, static, max_out):
+    """Per-shard range scans, unrolled in one program; row ``s`` of
+    ``lo``/``hi``/``lim`` holds shard ``s``'s queries."""
     S = jax.tree_util.tree_leaves(state)[0].shape[0]
     outs = [
         fops.range_scan(
             jax.tree_util.tree_map(lambda x: x[s], state),
-            lo[s], hi[s], lim[s], static=statics[s], max_out=max_out,
+            lo[s], hi[s], lim[s], static=static, max_out=max_out,
         )
         for s in range(S)
     ]
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
 
 
-@functools.partial(jax.jit, static_argnames=("fanout", "pad", "with_halves"))
-def _vgrow_bmat(keys, vals, *, fanout, pad, with_halves=False):
-    """Grow every shard's BMAT by ``pad`` KEY_MAX slots (stacked axis 1).
-    With ``with_halves`` the refreshed (hi, lo) decomposition of the grown
-    keys/fences comes back too, so callers carrying a persistent
-    ``state.halves`` keep it consistent without a separate device pass."""
+@functools.partial(jax.jit, static_argnames=("fanout", "pad"))
+def _vgrow_bmat(keys, vals, *, fanout, pad):
+    """Grow every shard's BMAT by ``pad`` KEY_MAX slots (stacked axis 1)."""
     keys = jnp.pad(keys, ((0, 0), (0, pad)), constant_values=KEY_MAX)
     vals = jnp.pad(vals, ((0, 0), (0, pad)))
     fences = jax.vmap(lambda k: _make_fences(k, fanout))(keys)
-    if with_halves:
-        return keys, vals, fences, split_key(keys) + split_key(fences)
-    return keys, vals, fences, None
+    return keys, vals, fences
 
 
 @dataclasses.dataclass
@@ -316,10 +297,6 @@ def _shell_from(
     sh._rng = np.random.default_rng(s)
     sh.n_lookups = 0
     sh.n_retrains = 0
-    # seed the shell's halves cache with the stacked row's slice — the
-    # identity anchor makes any later array swap rebuild it automatically
-    sh._halves = st.halves
-    sh._halves_src = sh._halves_sources() if st.halves is not None else None
     return sh
 
 
@@ -452,18 +429,6 @@ class ShardedUpLIF:
         self._drains: Dict[int, _DrainingCommit] = {}
         self._revisions: List[Tuple[int, int, int]] = []  # (ordinal, lo, hi)
         self._next_build_id = 0
-        # -- per-shard locate-strategy axis --------------------------------
-        # every shard starts on the platform-resolved config strategy; the
-        # telemetry-driven controller flips individual shards via
-        # set_shard_locate. _locate_requested is what was asked for;
-        # _locate_per_shard is what runs, held to the fused kernels' shape
-        # guard at the current stacked shapes. _locate_value/_jcodes are the
-        # cached dispatch form consumed by _static()/_read_view() (see
-        # _set_locate_axis; every restack and BMAT growth refreshes them).
-        self._locate_requested: List[str] = (
-            [resolve_locate(self.cfg.locate)] * self.n_shards
-        )
-        self._locate_obs: List[Tuple[np.ndarray, float, Tuple[str, ...]]] = []
         self._restack(shells)
 
     # -- stacking ------------------------------------------------------------
@@ -528,7 +493,7 @@ class ShardedUpLIF:
                 getattr(self, "rs_iters", 0),
             )
             self._meta = meta
-            self._set_locate_axis()
+            self._set_locate()
         assert cap % W == 0
 
     def _pad_shell(
@@ -557,14 +522,8 @@ class ShardedUpLIF:
             fences=_make_fences(bkeys, self.cfg.bmat_fanout),
             size=st.bmat.size,
         )
-        # padded arrays are NEW arrays, so the shell's cached halves (if
-        # any) do not cover the pads — rebuild the row's decomposition from
-        # the padded sources to keep the split-of-source invariant exact
-        halves = (
-            make_halves(slots, model, bmat) if st.halves is not None else None
-        )
         return UpLIFState(slots=slots, model=model, bmat=bmat,
-                          counters=st.counters, halves=halves)
+                          counters=st.counters)
 
     def _write_shard(self, s: int, sh: UpLIF) -> bool:
         """Fast path for single-shard maintenance: when the rebuilt shard
@@ -603,85 +562,24 @@ class ShardedUpLIF:
             self.state, self._meta[s], self.cfg, self.bmat_kind, s
         )
 
-    # -- per-shard locate dispatch ---------------------------------------------
-    def _set_locate_axis(self):
-        """Resolve ``_locate_requested`` against the fused kernels' shape
-        guard at the current stacked shapes into ``_locate_per_shard`` (the
-        strategies that execute: a shard asking for fused where the guard
-        refuses runs — and reports — spline), then refresh its cached
-        dispatch form.
-
-        ``_locate_value`` is what ``_static().locate`` carries: the single
-        strategy string when the assignment is uniform (the common case —
-        identical jit variants to a strategy-less router), else the SORTED
-        tuple of distinct strategies in play, so the static universe stays
-        inside the ≤7-value family regardless of which shard runs what.
-        ``_jcodes`` is the traced companion: per-shard int32 indices into
-        that tuple (None when uniform). Callers mutate ``_locate_requested``
-        or the state's shapes under the lock and call this before releasing
-        it."""
-        fits = self._fused_fits()
-        self._locate_per_shard = [
-            resolve_locate(r, fits) for r in self._locate_requested
-        ]
-        distinct = sorted(set(self._locate_per_shard))
-        if len(distinct) == 1:
-            self._locate_value = distinct[0]
-            self._jcodes = None
-        else:
-            self._locate_value = tuple(distinct)
-            pos = {strat: i for i, strat in enumerate(distinct)}
-            self._jcodes = jnp.asarray(
-                np.asarray(
-                    [pos[s] for s in self._locate_per_shard], dtype=np.int32
-                )
-            )
-
-    def set_shard_locate(self, s: int, strategy: str) -> bool:
-        """Pin shard ``s``'s locate strategy (the controller's
-        switch-locate action). Metadata-only: no state arrays move and the
-        strategy never changes what a query returns (the three strategies
-        are byte-identical by the equivalence contract), so — unlike
-        ``switch_bmat_type`` — this records NO revision and needs no
-        in-flight-build veto. Returns True when the strategy that executes
-        changed (fused where the shape guard refuses still runs spline)."""
-        assert 0 <= s < self.n_shards
-        strategy = resolve_locate(strategy)
-        with self._lock:
-            before = self._locate_per_shard[s]
-            self._locate_requested[s] = strategy
-            self._set_locate_axis()
-            return self._locate_per_shard[s] != before
-
-    def _fused_fits(self) -> bool:
+    # -- locate strategy -------------------------------------------------------
+    def _set_locate(self):
+        """Resolve ``cfg.locate`` against the fused kernels' shape guard at
+        the current stacked shapes: where the guard refuses fused, the
+        index runs (and reports) spline. Callers change the state's shapes
+        under the lock and call this before releasing it."""
         st = self.state
-        return fops.fused_fits(st.slots.keys, st.model, st.bmat)
+        self._locate = resolve_locate(
+            self.cfg.locate,
+            fops.fused_fits(st.slots.keys, st.model, st.bmat),
+        )
 
     def shard_locate(self) -> Tuple[str, ...]:
-        """Per-shard strategies that execute (telemetry snapshot input)."""
+        """The strategy each shard runs: the index's one strategy, S times."""
         with self._lock:
-            return tuple(self._locate_per_shard)
-
-    def locate_options(self) -> Tuple[str, ...]:
-        """Strategies a shard can run at the current stacked shapes."""
-        if self._fused_fits():
-            return LOCATE_STRATEGIES
-        return tuple(x for x in LOCATE_STRATEGIES if x != LOCATE_FUSED)
-
-    def drain_locate_obs(
-        self,
-    ) -> List[Tuple[np.ndarray, float, Tuple[str, ...]]]:
-        """Hand the accumulated (per-shard query counts, wall seconds,
-        strategy assignment) lookup observations to the telemetry layer
-        and reset the buffer."""
-        with self._lock:
-            obs, self._locate_obs = self._locate_obs, []
-        return obs
+            return (self._locate,) * self.n_shards
 
     def _static(self) -> UpLIFStatic:
-        # cfg.locate is resolved per shard at init/set_shard_locate time
-        # ("auto" -> spline) and held to the fused
-        # guard at every shape change (_set_locate_axis)
         return UpLIFStatic(
             window=self.cfg.window,
             movement_k=self.cfg.movement_k,
@@ -689,22 +587,19 @@ class ShardedUpLIF:
             insert_rounds=self.cfg.insert_rounds,
             fanout=self.cfg.bmat_fanout,
             bmat_kind=self.bmat_kind,
-            locate=self._locate_value,
+            locate=self._locate,
         )
 
     def _read_view(self):
-        """One consistent (state, boundaries, jbounds, codes, static) view.
+        """One consistent (state, boundaries, jbounds, static) view.
 
         Readers on other threads race the commit swap only at reference
-        granularity: grabbing all five under the swap lock guarantees the
-        static/boundary/strategy metadata matches the pytree generation, so
+        granularity: grabbing all four under the swap lock guarantees the
+        static and boundary metadata match the pytree generation, so
         a lookup issued mid-commit runs entirely against either the old or
         the new state — never a mix (the torn-read stress test pins this)."""
         with self._lock:
-            return (
-                self.state, self.boundaries, self._jbounds, self._jcodes,
-                self._static(),
-            )
+            return self.state, self.boundaries, self._jbounds, self._static()
 
     # -- routing ---------------------------------------------------------------
     def _route(self, keys: np.ndarray) -> np.ndarray:
@@ -761,28 +656,13 @@ class ShardedUpLIF:
             queries = np.asarray(queries, dtype=np.int64)
             q, n = self._pad_route(queries, width=pad_to)
         with obs.span("router.view"):
-            state, boundaries, jb, codes, static = self._read_view()
-        t0 = time.perf_counter()
+            state, _, jb, static = self._read_view()
         with obs.span("router.launch"):
-            f, v = fops.slookup(state, q, jb, codes, static=static)
-        with obs.span("router.wait"):  # sync: time the whole dispatch
+            f, v = fops.slookup(state, q, jb, static=static)
+        with obs.span("router.wait"):
             f = obs.fetch("router.lookup", f)
             v = obs.fetch("router.lookup", v)
-        dt = time.perf_counter() - t0
         self.n_lookups += n
-        if n:
-            # per-shard latency attribution for the locate-strategy
-            # controller: one searchsorted + bincount per dispatch is the
-            # whole host cost of the telemetry feed
-            counts = np.bincount(
-                np.searchsorted(boundaries, queries[:n], side="right"),
-                minlength=len(boundaries) + 1,
-            )
-            with self._lock:
-                if len(self._locate_obs) < 1024:  # bounded between drains
-                    self._locate_obs.append(
-                        (counts, dt, tuple(self._locate_per_shard))
-                    )
         return f[:n], v[:n]
 
     def _log_op(
@@ -827,8 +707,7 @@ class ShardedUpLIF:
             self._ensure_bmat_capacity(int(q.shape[0]))
         with obs.span("router.launch"):
             state, res = fops.sinsert(
-                self.state, q, vm, self._jbounds, self._jcodes,
-                static=self._static(),
+                self.state, q, vm, self._jbounds, static=self._static()
             )
         with self._lock:
             self.state = state
@@ -855,8 +734,7 @@ class ShardedUpLIF:
             q, n = self._pad_route(keys, width=pad_to)
         with obs.span("router.launch"):
             state, hit = fops.sdelete(
-                self.state, q, self._jbounds, self._jcodes,
-                static=self._static(),
+                self.state, q, self._jbounds, static=self._static()
             )
         with self._lock:
             self.state = state
@@ -892,13 +770,8 @@ class ShardedUpLIF:
         left = np.full(n, max_out, dtype=np.int64)
         if lim is not None:
             left = np.minimum(np.asarray(lim, dtype=np.int64), max_out)
-        with obs.span("router.view"), self._lock:
-            state, boundaries = self.state, self.boundaries
-            static = self._static()
-            per_shard = tuple(self._locate_per_shard)
-        # range scans unroll per shard, so mixed dispatch is just each
-        # shard's scan compiled under its own (uniform) strategy
-        statics = tuple(static._replace(locate=loc) for loc in per_shard)
+        with obs.span("router.view"):
+            state, boundaries, _, static = self._read_view()
         obs.count("range.queries", n)
         upper = np.append(boundaries, KEY_MAX)  # shard s owns keys < upper[s]
         parts_k: List[List[np.ndarray]] = [[] for _ in range(n)]
@@ -908,7 +781,7 @@ class ShardedUpLIF:
             rescan = None
             while len(qi):
                 ks, vs, cn, covered, front = self._range_pass(
-                    state, statics, sid, cur, hi[qi], left[qi], max_out
+                    state, static, sid, cur, hi[qi], left[qi], max_out
                 )
                 with obs.span("router.assemble"):
                     for i, q in enumerate(qi):
@@ -934,12 +807,12 @@ class ShardedUpLIF:
             obs.count("range.keys", sum(len(k) for k in out_k))
         return out_k, out_v
 
-    def _range_pass(self, state, statics, sid, lo, hi, lim, max_out):
+    def _range_pass(self, state, static, sid, lo, hi, lim, max_out):
         """One run of ``_vrange``: query i in a row of shard ``sid[i]``.
         Returns, per query, its pairs (``[n, max_out]``), count, whether
         its answer is whole and its slice's front."""
         with obs.span("router.prepare"):
-            S = len(statics)
+            S = state.slots.keys.shape[0]
             counts = np.bincount(sid, minlength=S)
             order = np.argsort(sid, kind="stable")
             rows = np.empty(len(sid), dtype=np.int64)
@@ -956,7 +829,7 @@ class ShardedUpLIF:
         with obs.span("router.launch"):
             res = _vrange(
                 state, jnp.asarray(lo_m), jnp.asarray(hi_m),
-                jnp.asarray(lim_m), statics=statics, max_out=max_out,
+                jnp.asarray(lim_m), static=static, max_out=max_out,
             )
         with obs.span("router.wait"):
             res = obs.fetch("router.range", res)
@@ -996,7 +869,7 @@ class ShardedUpLIF:
         """Global logical rank = shard-local rank + total live keys in the
         shards left of the owning shard."""
         queries = np.asarray(queries, dtype=np.int64)
-        state, boundaries, jb, codes, static = self._read_view()
+        state, boundaries, jb, static = self._read_view()
         # a preceding shard contributes its live in-place keys plus its FULL
         # BMAT entry count — the bias r(k) counts tombstones too, matching
         # the single-shard BMAT rank semantics
@@ -1005,7 +878,7 @@ class ShardedUpLIF:
         )
         base = np.concatenate([[0], np.cumsum(sizes)[:-1]])
         q, n = self._pad_route(queries)
-        rank = np.asarray(fops.srank(state, q, jb, codes, static=static))
+        rank = np.asarray(fops.srank(state, q, jb, static=static))
         sid = np.searchsorted(boundaries, queries, side="right")
         return rank[:n] + base[sid]
 
@@ -1016,29 +889,25 @@ class ShardedUpLIF:
         need = int(sizes.max()) + incoming
         if need <= bcap - 1:
             return
-        new_cap = grow_capacity(need)
-        keys, vals, fences, bh = _vgrow_bmat(
+        self._grow_bmat(grow_capacity(need) - bcap)
+
+    def _grow_bmat(self, pad: int):
+        """Grow every shard's BMAT by ``pad`` slots; the new shapes may
+        change what the fused guard admits."""
+        keys, vals, fences = _vgrow_bmat(
             self.state.bmat.keys,
             self.state.bmat.vals,
             fanout=self.cfg.bmat_fanout,
-            pad=new_cap - bcap,
-            with_halves=self.state.halves is not None,
+            pad=pad,
         )
         with self._lock:
-            halves = self.state.halves
-            if bh is not None:
-                halves = halves._replace(
-                    bmat_hi=bh[0], bmat_lo=bh[1],
-                    fence_hi=bh[2], fence_lo=bh[3],
-                )
             self.state = self.state._replace(
                 bmat=BMATState(
                     keys=keys, vals=vals, fences=fences,
                     size=self.state.bmat.size,
                 ),
-                halves=halves,
             )
-            self._set_locate_axis()
+            self._set_locate()
 
     # -- versioned-state protocol (plan/build/commit; DESIGN.md §8) ------------
     @property
@@ -1304,8 +1173,6 @@ class ShardedUpLIF:
                 self._jbounds = jnp.asarray(self.boundaries)
                 self.n_shards += 1
                 self.n_splits += 1
-                # both halves inherit the split shard's locate strategy
-                self._locate_requested.insert(s, self._locate_requested[s])
                 self._restack(live[:s] + list(shells) + live[s + 1:])
         elif delta.kind == "merge":
             live = [self._unstack_shell(i) for i in range(self.n_shards)]
@@ -1314,8 +1181,6 @@ class ShardedUpLIF:
                 self._jbounds = jnp.asarray(self.boundaries)
                 self.n_shards -= 1
                 self.n_merges += 1
-                # the merged shard keeps the left member's strategy
-                del self._locate_requested[s + 1]
                 self._restack(live[:s] + list(shells) + live[s + 2:])
         else:
             raise ValueError(f"unknown delta kind: {delta.kind}")
@@ -1398,7 +1263,6 @@ class ShardedUpLIF:
             self._jbounds = jnp.asarray(self.boundaries)
             self.n_shards += 1
             self.n_splits += 1
-            self._locate_requested.insert(s, self._locate_requested[s])
             self._restack(shells[:s] + [left, right] + shells[s + 1:])
             self._record_revision(lo, hi)
         return True
@@ -1426,7 +1290,6 @@ class ShardedUpLIF:
             self._jbounds = jnp.asarray(self.boundaries)
             self.n_shards -= 1
             self.n_merges += 1
-            del self._locate_requested[s + 1]
             self._restack(shells[:s] + [merged] + shells[s + 2:])
             self._record_revision(lo, hi)
         return True
@@ -1440,29 +1303,7 @@ class ShardedUpLIF:
         need = int(per_shard_capacity)
         if need <= bcap:
             return False
-        new_cap = pow2_at_least(need)
-        keys, vals, fences, bh = _vgrow_bmat(
-            self.state.bmat.keys,
-            self.state.bmat.vals,
-            fanout=self.cfg.bmat_fanout,
-            pad=new_cap - bcap,
-            with_halves=self.state.halves is not None,
-        )
-        with self._lock:
-            halves = self.state.halves
-            if bh is not None:
-                halves = halves._replace(
-                    bmat_hi=bh[0], bmat_lo=bh[1],
-                    fence_hi=bh[2], fence_lo=bh[3],
-                )
-            self.state = self.state._replace(
-                bmat=BMATState(
-                    keys=keys, vals=vals, fences=fences,
-                    size=self.state.bmat.size,
-                ),
-                halves=halves,
-            )
-            self._set_locate_axis()
+        self._grow_bmat(pow2_at_least(need) - bcap)
         return True
 
     # -- accounting ------------------------------------------------------------

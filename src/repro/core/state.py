@@ -17,7 +17,7 @@ keyspace over many such states.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -72,71 +72,26 @@ class Counters(NamedTuple):
     min_granularity: jnp.ndarray  # int64 — smallest failed-window key span
 
 
-class KeyHalves(NamedTuple):
-    """Persistent (hi:int32, lo:uint32) decomposition of every int64 key
-    array the fused Pallas kernels read, plus the float32 spline positions.
-
-    The fused adapters in ``repro.kernels.ops`` consume pre-split halves;
-    without this pytree member they re-split the O(S·cap) slot/BMAT arrays
-    inside every jitted call. Carrying the halves in ``UpLIFState`` amortizes
-    that conversion per *state version*: built once at construction/retrain
-    (``make_halves``), maintained incrementally by the write paths in
-    ``fops`` alongside the int64 source arrays. Invariant (pinned by the
-    property suite): every field is byte-identical to a fresh
-    ``kernels.ops.split_key`` of its int64 source.
-    """
-
-    slot_hi: jnp.ndarray      # int32  [cap] / [S, cap] — slots.keys >> 32
-    slot_lo: jnp.ndarray      # uint32 — slots.keys & 0xFFFFFFFF
-    spline_hi: jnp.ndarray    # int32  — model.spline_keys >> 32
-    spline_lo: jnp.ndarray    # uint32
-    spline_pos32: jnp.ndarray  # float32 — model.spline_pos.astype(f32)
-    bmat_hi: jnp.ndarray      # int32  — bmat.keys >> 32
-    bmat_lo: jnp.ndarray      # uint32
-    fence_hi: jnp.ndarray     # int32  — bmat.fences >> 32
-    fence_lo: jnp.ndarray     # uint32
-
-
 class UpLIFState(NamedTuple):
     """The whole index as one pytree (slots + model + BMAT + counters).
 
-    ``halves`` is the optional persistent (hi, lo) decomposition: ``None``
-    (the per-call re-split baseline) vs present is a treedef difference, so
-    the two modes trace separately and never mix inside one jit cache entry.
+    Keys are stored once, as int64. The fused Pallas kernels take
+    (int32 hi, uint32 lo) halves; their adapters in ``repro.kernels.ops``
+    split the arrays they read inside each call, so no write path keeps a
+    second copy of the keys up to date.
     """
 
     slots: SlotsState
     model: RadixSplineModel
     bmat: BMATState
     counters: Counters
-    halves: Optional[KeyHalves] = None
-
-
-def make_halves(
-    slots: SlotsState, model: RadixSplineModel, bmat: BMATState
-) -> KeyHalves:
-    """Build the full decomposition fresh (construction / retrain / pad)."""
-    from repro.kernels.ops import split_key  # no cycle: kernels never import core
-
-    slot_hi, slot_lo = split_key(slots.keys)
-    spline_hi, spline_lo = split_key(model.spline_keys)
-    bmat_hi, bmat_lo = split_key(bmat.keys)
-    fence_hi, fence_lo = split_key(bmat.fences)
-    return KeyHalves(
-        slot_hi=slot_hi,
-        slot_lo=slot_lo,
-        spline_hi=spline_hi,
-        spline_lo=spline_lo,
-        spline_pos32=model.spline_pos.astype(jnp.float32),
-        bmat_hi=bmat_hi,
-        bmat_lo=bmat_lo,
-        fence_hi=fence_hi,
-        fence_lo=fence_lo,
-    )
 
 
 class UpLIFStatic(NamedTuple):
-    """Jit-stable scalars for the op suite (hashable; static argument)."""
+    """Jit-stable scalars for the op suite (hashable; static argument).
+
+    ``locate`` is one strategy for the whole index: on a stacked state
+    every shard runs it, so a wave is one locate and one rank."""
 
     window: int         # W — insert/last-mile window (power of two)
     movement_k: int     # K — max elements shifted per in-place insert
@@ -144,11 +99,8 @@ class UpLIFStatic(NamedTuple):
     insert_rounds: int  # in-place retry rounds before BMAT overflow
     fanout: int         # B+MAT fence fanout
     bmat_kind: str      # 'rbmat' | 'b+mat'
-    # one concrete strategy (str), or — for mixed per-shard dispatch in the
-    # stacked ops — a sorted tuple of the DISTINCT strategies in play; the
-    # traced per-shard ``codes`` array indexes into that tuple. Keeping the
-    # tuple sorted/deduplicated bounds the static universe at 7 values, so
-    # controller flips never grow the jit cache past the warmed family.
+    # the one strategy every shard of the index runs, already resolved
+    # (``resolve_locate``) against the fused kernels' shape guard
     locate: str         # LOCATE_SPLINE | LOCATE_BINSEARCH | LOCATE_FUSED
 
 

@@ -47,7 +47,6 @@ from repro.core.state import (
     UpLIFState,
     UpLIFStatic,
     init_counters,
-    make_halves,
     resolve_locate,
 )
 from repro.core.types import GMMState, KEY_MAX, TOMBSTONE, SlotsState
@@ -77,18 +76,10 @@ class UpLIFConfig:
     bmat_type: str = BPMAT
     bmat_fanout: int = 16
     bmat_capacity: int = 4096    # initial delta-buffer capacity (grows)
-    # locate/rank strategy for the fops hot path: "auto" resolves per
-    # platform (fused Pallas kernels on TPU, jnp spline elsewhere); tests
-    # and benches pin "spline" / "binsearch" / "fused" explicitly.
+    # locate/rank strategy of the whole index: "auto" is the jnp spline
+    # path on every platform; "spline" / "binsearch" / "fused" pin one
+    # (fused runs only where the kernels' shape guard admits the state)
     locate: str = LOCATE_AUTO
-    # carry the persistent (hi, lo) key decomposition in the state pytree
-    # so the fused kernels never re-split slot/BMAT arrays per call. Carried
-    # unconditionally (not only under ``locate="fused"``) so every shell in
-    # a router shares one treedef regardless of per-shard strategy; the
-    # memory cost is 1.5x the key arrays only (values are untouched).
-    # ``False`` is the per-call re-split baseline the locate_sweep bench
-    # measures against.
-    persist_halves: bool = True
 
     def __post_init__(self):
         assert self.window & (self.window - 1) == 0
@@ -104,11 +95,6 @@ bucket_width = shapes.bucket_width
 
 class UpLIF:
     """Batched updatable learned index (thin shell over repro.core.fops)."""
-
-    # Class-level locate override for baselines (e.g. the B+Tree baseline
-    # pins a pure binary search); None defers to cfg.locate, which "auto"-
-    # resolves per platform (fused Pallas kernels on TPU).
-    LOCATE: Optional[str] = None
 
     def __init__(
         self,
@@ -188,36 +174,6 @@ class UpLIF:
         )
 
     # -- functional-core plumbing ---------------------------------------------
-    def _halves_sources(self) -> tuple:
-        """The key arrays the (hi, lo) decomposition is derived from."""
-        return (
-            self.slots.keys,
-            self.rs_model.spline_keys,
-            self.bmat.state.keys,
-            self.bmat.state.fences,
-        )
-
-    def _current_halves(self):
-        """Cached persistent decomposition, invalidated by IDENTITY: any
-        mutation path that swaps a source key array (fops adoption, BMAT
-        grow/rebuild/merge/compact, bulk load, retrain) breaks the ``is``
-        check and forces a rebuild — no per-site invalidation hooks to keep
-        in sync. Ops that adopt a fops-maintained ``state.halves`` refresh
-        the cache instead (``_adopt``), so the rebuild only runs on the
-        rare host-side structural paths."""
-        if not self.cfg.persist_halves:
-            return None
-        src = self._halves_sources()
-        cached = getattr(self, "_halves", None)
-        cached_src = getattr(self, "_halves_src", None)
-        if cached is None or cached_src is None or any(
-            a is not b for a, b in zip(src, cached_src)
-        ):
-            cached = make_halves(self.slots, self.rs_model, self.bmat.state)
-            self._halves = cached
-            self._halves_src = src
-        return cached
-
     @property
     def fstate(self) -> UpLIFState:
         """The whole index as a pure pytree (zero-copy view of the arrays)."""
@@ -226,15 +182,14 @@ class UpLIF:
             model=self.rs_model,
             bmat=self.bmat.state,
             counters=self._counters,
-            halves=self._current_halves(),
         )
 
     def locate_strategy(self) -> str:
-        """Concrete locate strategy for this call: the class override (the
-        baselines' hook) wins, then cfg.locate ("auto" resolved), held to
-        the fused kernels' guard at the current shapes."""
+        """Concrete locate strategy for this call: cfg.locate ("auto"
+        resolved), held to the fused kernels' guard at the current
+        shapes."""
         return resolve_locate(
-            self.LOCATE or self.cfg.locate,
+            self.cfg.locate,
             fops.fused_fits(self.slots.keys, self.rs_model, self.bmat.state),
         )
 
@@ -259,11 +214,6 @@ class UpLIF:
         self.slots = state.slots
         self.bmat.state = state.bmat
         self._counters = state.counters
-        if state.halves is not None:
-            # fops maintained the decomposition alongside the int64 arrays:
-            # adopt it and re-anchor the identity cache to the new sources
-            self._halves = state.halves
-            self._halves_src = self._halves_sources()
 
     # -- counters (host views of the device pytree) ---------------------------
     @property

@@ -168,8 +168,6 @@ def locate_fusable(cap: int, n_knots: int, n_table: int, n_shards: int) -> bool:
 def fused_locate(
     table, spline_keys, spline_pos, shift, slot_keys, queries, sid,
     *, n_table: int, n_knots: int, cap: int, window: int, rs_iters: int,
-    spline_hi=None, spline_lo=None, spline_pos32=None,
-    slot_hi=None, slot_lo=None,
 ):
     """Jit-traceable adapter around ``fused_locate_pallas``.
 
@@ -177,21 +175,13 @@ def fused_locate(
     shard axis ([S*T], [S*K], [S*cap]); ``shift`` is the per-shard [S] radix
     shift; ``sid`` maps each query to its shard (all zeros for a single
     shard). Per-query base offsets and block padding are handled here;
-    returns (j, icap) as int64 with the ``fops._locate`` contract.
-
-    When the caller carries a persistent decomposition
-    (``state.halves``), pass the pre-split ``spline_hi``/``spline_lo``/
-    ``spline_pos32``/``slot_hi``/``slot_lo`` and the O(S·cap) int64 ->
-    (hi, lo) conversion is skipped entirely (the int64 source arrays are
-    then dead inputs that XLA eliminates). Only the O(batch) query split
-    stays per-call. Without them the split runs here, per call."""
+    returns (j, icap) as int64 with the ``fops._locate`` contract. The
+    int64 knot and slot keys are split into the kernel's (hi, lo) halves
+    here, inside the caller's program, on every call."""
     L = min(3 * window, cap)
-    if spline_hi is None:
-        spline_hi, spline_lo = split_key(spline_keys)
-    if slot_hi is None:
-        slot_hi, slot_lo = split_key(slot_keys)
-    if spline_pos32 is None:
-        spline_pos32 = spline_pos.astype(jnp.float32)
+    spline_hi, spline_lo = split_key(spline_keys)
+    slot_hi, slot_lo = split_key(slot_keys)
+    spline_pos32 = spline_pos.astype(jnp.float32)
     q_hi, q_lo = split_key(queries)
     n = queries.shape[0]
     m = pad_queries(n)
@@ -219,17 +209,13 @@ def rank_fusable(n_keys: int, n_fences: int) -> bool:
 
 
 def bmat_rank_fused(keys, fences, queries, sid, *, cap: int, nf: int,
-                    fanout: int, keys_hi=None, keys_lo=None,
-                    fences_hi=None, fences_lo=None):
+                    fanout: int):
     """Jit-traceable shard-offset rank: ``keys``/``fences`` flat over the
     shard axis, ``sid`` per query (zeros for a single shard). Returns the
-    shard-local searchsorted-left rank as int32 (callers widen). Pre-split
-    halves (``keys_hi``..``fences_lo``, from a persistent ``state.halves``)
-    skip the per-call buffer decomposition; only queries split here."""
-    if keys_hi is None:
-        keys_hi, keys_lo = split_key(keys)
-    if fences_hi is None:
-        fences_hi, fences_lo = split_key(fences)
+    shard-local searchsorted-left rank as int32 (callers widen). The keys
+    and fences are split into (hi, lo) halves here, on every call."""
+    keys_hi, keys_lo = split_key(keys)
+    fences_hi, fences_lo = split_key(fences)
     qh, ql = split_key(queries)
     n = queries.shape[0]
     m = pad_queries(n)

@@ -11,9 +11,9 @@ Closes the paper's adaptive loop over the functional sharded core:
                distribution-shift signal;
   controller — per-shard Q-learning (Algorithm 1) with the extended masked
                action space keep / retrain-shard / switch-BMAT /
-               split-shard / merge-shards / switch-locate (repin one
-               shard's locate strategy to its latency-EWMA argmin),
-               persisted per workload signature through ``QTableStore``;
+               split-shard / merge-shards, persisted per workload
+               signature through ``QTableStore``. The locate strategy is
+               not a tuning action: one strategy runs per index;
   scheduler  — plan/build/commit pipeline: decisions become declarative
                ``MaintenancePlan`` records admitted by interval overlap +
                aggregate budget; builds run inline (sync) or on the
@@ -47,7 +47,6 @@ from repro.tuning.controller import (  # noqa: F401
     A_RETRAIN_SHARD,
     A_SPLIT_SHARD,
     A_SWITCH_BMAT,
-    A_SWITCH_LOCATE,
     ACTION_NAMES,
     ACTIONS,
     ControllerConfig,

@@ -43,35 +43,18 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.bmat import RBMAT
-from repro.core.state import LOCATE_FUSED, LOCATE_STRATEGIES
 from repro.tuning.telemetry import TelemetrySnapshot
 
-# Extended per-shard action space (paper A1–A3 + structural A4/A5 + the
-# per-shard locate-dispatch axis A6)
+# Extended per-shard action space (paper A1–A3 + structural A4/A5)
 A_KEEP = 0           # maintain current structure
 A_RETRAIN_SHARD = 1  # full retrain of the focus shard (absorbs its BMAT)
 A_SWITCH_BMAT = 2    # flip RBMAT <-> B+MAT (global: layout is shared)
 A_SPLIT_SHARD = 3    # split the focus shard at its median key
 A_MERGE_SHARDS = 4   # merge the coldest adjacent shard pair
-A_SWITCH_LOCATE = 5  # repin the focus shard's locate strategy (per shard)
 ACTIONS = (A_KEEP, A_RETRAIN_SHARD, A_SWITCH_BMAT, A_SPLIT_SHARD,
-           A_MERGE_SHARDS, A_SWITCH_LOCATE)
+           A_MERGE_SHARDS)
 ACTION_NAMES = ("keep", "retrain_shard", "switch_bmat", "split_shard",
-                "merge_shards", "switch_locate")
-
-
-def locate_candidates(options: Tuple[str, ...]) -> Tuple[str, ...]:
-    """Strategies the controller may pin a shard to, out of the ones the
-    router can run (``TelemetrySnapshot.locate_options``). Off TPU the
-    fused kernels only run in interpret mode — a correctness proxy orders
-    of magnitude slower than the jnp paths — so fused is only a candidate
-    where it compiles for the chip. The dispatch axis itself (mixed
-    per-shard strategies in one wave) is exercised either way."""
-    from repro.kernels.ops import on_tpu
-
-    if on_tpu():
-        return tuple(options)
-    return tuple(s for s in options if s != LOCATE_FUSED)
+                "merge_shards")
 
 
 # state discretization edges
@@ -153,38 +136,7 @@ class ShardTuningController:
             and int((live[:-1] + live[1:]).min()) <= self.cfg.merge_max_keys
         )
         mask[A_MERGE_SHARDS] = pair_ok
-        # switching the locate strategy is only a representable choice when
-        # the latency telemetry actually argues for a different one — the
-        # action is then deterministic (pin the argmin), so exposing it
-        # with nothing to change would just be a noisy KEEP
-        mask[A_SWITCH_LOCATE] = (
-            bool(snap.locate_strategy)
-            and self.pick_locate(snap, s) != snap.locate_strategy[s]
-        )
         return mask
-
-    def pick_locate(self, snap: TelemetrySnapshot, s: int) -> str:
-        """Latency-argmin locate strategy for shard ``s``.
-
-        Reads the per-(shard, strategy) seconds-per-query EWMAs. A
-        strategy the shard has never run under gets an OPTIMISTIC prior
-        (half the best observed latency) so it is tried rather than
-        starved; with no observations at all the current assignment stands
-        (no evidence, no churn). Leaving the current strategy requires a
-        ≥10% predicted win — hysteresis against EWMA noise flapping the
-        jit-variant set."""
-        cur = snap.locate_strategy[s]
-        cands = locate_candidates(snap.locate_options or LOCATE_STRATEGIES)
-        obs = {c: snap.locate_lat.get((s, c)) for c in cands}
-        observed = [v for v in obs.values() if v is not None]
-        if not observed:
-            return cur
-        prior = 0.5 * min(observed)
-        score = {c: (v if v is not None else prior) for c, v in obs.items()}
-        best = min(cands, key=lambda c: score[c])
-        if cur in score and score[best] >= 0.9 * score[cur]:
-            return cur
-        return best
 
     @staticmethod
     def coldest_pair(snap: TelemetrySnapshot) -> int:
@@ -297,9 +249,10 @@ class ShardTuningController:
     def import_q(self, table: dict, only_missing: bool = True):
         """Warm-start from a stored table. ``only_missing`` keeps rows this
         session already learned (its own measurements beat the prior).
-        Stored rows narrower than the live action space (saved before an
-        action was added, e.g. switch_locate) zero-pad: a zero Q is
-        exactly the value an unseen action starts with."""
+        Stored rows narrower than the live action space zero-pad: a zero Q
+        is exactly the value an unseen action starts with. Wider rows
+        (saved while a sixth action existed) keep their first
+        ``len(ACTIONS)`` columns, whose actions kept their indices."""
         for ks, row in table.items():
             k = tuple(int(x) for x in ks.split(","))
             if only_missing and k in self.q:

@@ -57,7 +57,6 @@ from repro.tuning.controller import (
     A_MERGE_SHARDS,
     A_RETRAIN_SHARD,
     A_SWITCH_BMAT,
-    A_SWITCH_LOCATE,
     ACTION_NAMES,
     ShardTuningController,
 )
@@ -613,28 +612,6 @@ class MaintenanceScheduler:
                     index.switch_bmat_type()
                     self._charge(A_SWITCH_BMAT, time.perf_counter() - sw0)
                     changed = True
-            elif a == A_SWITCH_LOCATE:
-                # metadata-only: no arrays move, results are byte-identical
-                # across strategies, so — unlike switch_bmat — the repin
-                # needs neither an in-flight-build veto nor a revision
-                # record; only overload sheds it (the flipped wave may pay
-                # one jit variant)
-                if self.pressure >= 1:
-                    a, deferred = A_KEEP, True
-                elif self._estimated_cost(a) > self._available():
-                    a, deferred = A_KEEP, True
-                else:
-                    pick = self.controller.pick_locate(snap, s)
-                    sw0 = time.perf_counter()
-                    changed = index.set_shard_locate(s, pick)
-                    if changed:
-                        self.controller.action_counts[a] += 1
-                        self._charge(
-                            A_SWITCH_LOCATE, time.perf_counter() - sw0
-                        )
-                    else:  # telemetry moved since the mask: nothing to change
-                        a = A_KEEP
-                        self.controller.action_counts[A_KEEP] += 1
             else:
                 self.controller.action_counts[A_KEEP] += 1
 

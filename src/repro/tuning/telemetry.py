@@ -15,7 +15,7 @@ serving loop reports, normalizing the reward terms of Algorithm 1.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, NamedTuple, Optional, Sequence, Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -76,16 +76,6 @@ class TelemetrySnapshot:
     throughput_ewma: float       # ops/s over recent waves
     memory_ewma: float           # index bytes
     range_lat_ewma: float        # seconds per range query (0 = none seen)
-    # per-shard locate-strategy axis: the current assignment plus the
-    # (shard, strategy) -> seconds-per-query latency EWMAs the controller's
-    # switch-locate action reads (empty until lookups have been observed)
-    locate_strategy: Tuple[str, ...] = ()
-    locate_lat: Dict[Tuple[int, str], float] = dataclasses.field(
-        default_factory=dict
-    )
-    # strategies the router can run at its current shapes (fused only
-    # where the fused kernels' guard admits them)
-    locate_options: Tuple[str, ...] = ()
 
     def shard_measures(self, s: int) -> dict:
         """Section 4.1 measure dict for shard ``s`` (controller state input)."""
@@ -120,9 +110,6 @@ class Telemetry:
         self.n_waves = 0
         self.n_range_obs = 0
         self._snap_count = 0
-        # (shard, locate strategy) -> EWMA seconds per lookup query
-        self.locate_lat: Dict[Tuple[int, str], float] = {}
-        self._locate_n_shards: Optional[int] = None
 
     def observe_wave(self, n_ops: int, seconds: float):
         """Feed one request wave's measured throughput into the EWMA."""
@@ -150,43 +137,9 @@ class Telemetry:
         )
         self.n_range_obs += 1
 
-    def observe_locate(
-        self,
-        obs: Sequence[Tuple[np.ndarray, float, Tuple[str, ...]]],
-        n_shards: int,
-    ):
-        """Fold drained lookup observations into the per-(shard, strategy)
-        latency EWMAs. A lookup wave is ONE joint dispatch, so per-shard
-        attribution is by query share: every shard that served queries
-        observes the wave's per-query latency, with an EWMA step scaled by
-        its share of the wave — shards carrying the traffic move their
-        estimate fastest, idle shards learn nothing. Splits/merges renumber
-        shards, so a shard-count change resets the table (stale
-        attribution is worse than a cold start)."""
-        if self._locate_n_shards is not None and n_shards != self._locate_n_shards:
-            self.locate_lat.clear()
-        self._locate_n_shards = n_shards
-        a = self.cfg.ewma_alpha
-        for counts, seconds, strategies in obs:
-            total = int(counts.sum())
-            if total <= 0 or seconds <= 0:
-                continue
-            lat = seconds / total
-            for s, strat in enumerate(strategies):
-                c = int(counts[s]) if s < len(counts) else 0
-                if c == 0:
-                    continue
-                key = (s, strat)
-                prev = self.locate_lat.get(key)
-                w = a * c / total
-                self.locate_lat[key] = (
-                    lat if prev is None else (1 - w) * prev + w * lat
-                )
-
     @obs.traced("tuner.telemetry")
     def snapshot(self, index: ShardedUpLIF) -> TelemetrySnapshot:
         """Read the per-shard signals (one device reduce + one transfer)."""
-        self.observe_locate(index.drain_locate_obs(), index.n_shards)
         sig = obs.fetch("tuner.telemetry", shard_signals(index.state))
         bsz = np.asarray(sig.bmat_size)
         heights = np.asarray(
@@ -216,7 +169,4 @@ class Telemetry:
             throughput_ewma=self.throughput_ewma,
             memory_ewma=self.memory_ewma,
             range_lat_ewma=self.range_lat_ewma,
-            locate_strategy=index.shard_locate(),
-            locate_lat=dict(self.locate_lat),
-            locate_options=index.locate_options(),
         )

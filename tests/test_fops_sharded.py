@@ -424,7 +424,7 @@ def test_sinsert_rounds_stop_when_no_key_can_be_placed(case, n_rounds):
             sh._adopt(st)
         shells.append(sh)
 
-    state, res = fops.sinsert(idx.state, q, vm, idx._jbounds, idx._jcodes,
+    state, res = fops.sinsert(idx.state, q, vm, idx._jbounds,
                               static=idx._static())
     assert int(res.n_rounds) == n_rounds
     if case == "window_heads":
@@ -483,9 +483,9 @@ def test_device_phases_are_named_in_the_lowered_programs():
     keys = make_keys(3000, 308)
     idx = ShardedUpLIF(keys, keys, CFG, n_shards=2)
     q = _pad(keys[:10], KEY_MAX)
-    args = (idx.state, q, idx._jbounds, idx._jcodes)
-    look = fops.slookup.lower(*args, static=idx._static())
-    ins = fops.sinsert.lower(idx.state, q, q, idx._jbounds, idx._jcodes,
+    look = fops.slookup.lower(idx.state, q, idx._jbounds,
+                              static=idx._static())
+    ins = fops.sinsert.lower(idx.state, q, q, idx._jbounds,
                              static=idx._static())
 
     def scopes(lowered, fn):

@@ -19,6 +19,8 @@ Strategies go through ``tests/_hypothesis_compat``: with hypothesis
 installed each case explores random tapes; without it the deterministic
 boundary grid runs the same oracles.
 """
+import types
+
 import numpy as np
 import pytest
 
@@ -27,6 +29,8 @@ import jax.numpy as jnp
 from repro.core import ShardedUpLIF, UpLIF
 from repro.core.uplif import UpLIFConfig
 from repro.kernels import ops as kops
+from repro.tuning.controller import A_RETRAIN_SHARD
+from repro.tuning.executor import build as build_plan
 from tests._hypothesis_compat import HealthCheck, given, settings, st
 from tests.conftest import make_keys
 
@@ -136,72 +140,157 @@ def test_stacked_strategy_equivalence(seed, n_shards):
         _assert_identical(strat, results["spline"], results[strat])
 
 
-@settings(max_examples=4, deadline=None,
-          suppress_health_check=[HealthCheck.too_slow])
-@given(seed=st.integers(0, 1), assignment=st.sampled_from([
-    ("spline", "binsearch", "fused"),
-    ("fused", "binsearch", "fused"),
-    ("binsearch", "spline", "spline"),
-]))
-def test_mixed_per_shard_strategy_equivalence(seed, assignment):
-    """Per-shard dispatch (ISSUE 8): one wave may run DIFFERENT locate
-    strategies on different shards — a per-query strategy mask partitions
-    the wave across at most three launches, each query taking (j, ins_cap)
-    from its own shard's branch. Every visible result must match the
-    uniform-strategy router on the same tape, shard-boundary keys and
-    mid-tape strategy flips included. radix_bits=14 keeps the fused guard
-    open at S=3, so the fused shards run the kernels."""
-    base, vals, ops_tape, probes, ranges = _tape(seed)
-    cfg = UpLIFConfig(locate="spline", batch_bucket=256, radix_bits=14)
-    ref = ShardedUpLIF(base, vals, cfg, n_shards=3)
-    mixed = ShardedUpLIF(base, vals, cfg, n_shards=3)
-    for s, strat in enumerate(assignment):
-        mixed.set_shard_locate(s, strat)
-    assert mixed.shard_locate() == assignment
-    b = ref.boundaries.astype(np.int64)
-    probes_b = np.concatenate([probes, b, b - 1, b + 1])
-    r_ref = _run_tape(ref, ops_tape, probes_b, ranges)
-    r_mix = _run_tape(mixed, ops_tape, probes_b, ranges)
-    _assert_identical(f"mixed{assignment}", r_ref, r_mix)
-    assert ref.size == mixed.size
-    assert mixed.shard_locate() == assignment
-    # a controller flip mid-stream must not disturb state or results
-    mixed.set_shard_locate(1, "fused")
-    assert mixed.shard_locate()[1] == "fused"
-    fa, va = ref.lookup(probes_b)
-    fb, vb = mixed.lookup(probes_b)
-    np.testing.assert_array_equal(fa, fb)
-    np.testing.assert_array_equal(va, vb)
+WRITE_PATHS = ("dups_revivals", "deletes", "bmat_growth", "commit_mid_drain",
+               "split_merge")
 
 
-def test_mixed_codes_reuse_jit_variants():
-    """Jit-cache flatness (§7.5 shapes discipline): the per-shard strategy
-    CODES are a traced argument — repinning shards within the same
-    distinct-strategy set changes code values, never the trace. Only the
-    sorted deduplicated strategy tuple is static, so controller flips stay
-    inside the warmed pow2 variant family instead of growing the cache per
-    assignment permutation."""
-    from repro.core import fops
+class _Ref:
+    """Sorted-dict model of the index: the answers every write path must
+    leave behind, whatever strategy located the keys."""
 
-    keys = make_keys(900, 3, hi=KEY_HI)
-    cfg = UpLIFConfig(locate="spline", batch_bucket=256)
-    idx = ShardedUpLIF(keys, keys + 1, cfg, n_shards=3)
-    idx.set_shard_locate(0, "binsearch")  # distinct set {binsearch, spline}
-    q = keys[:100]
-    idx.lookup(q)   # warm the mixed variant at this pow2 pad width
-    idx.delete(keys[:0])
-    n0 = fops.slookup._cache_size()
-    nd = fops.sdelete._cache_size()
-    # permute the assignment inside the same distinct set: same static
-    # tuple, same shapes, different code values -> the warmed variants
-    # must serve every one of them
-    for flip in ((0, "spline", 1, "binsearch"), (1, "spline", 2, "binsearch")):
-        idx.set_shard_locate(flip[0], flip[1])
-        idx.set_shard_locate(flip[2], flip[3])
-        idx.lookup(q)
-        idx.delete(keys[:0])
-    assert fops.slookup._cache_size() == n0
-    assert fops.sdelete._cache_size() == nd
+    def __init__(self, keys, vals):
+        self.d = dict(zip(keys.tolist(), vals.tolist()))
+
+    def insert(self, keys, vals):
+        self.d.update(zip(keys.tolist(), vals.tolist()))  # last one wins
+
+    def delete(self, keys):
+        hit = np.asarray([k in self.d for k in keys.tolist()])
+        for k in keys.tolist():
+            self.d.pop(k, None)
+        return hit
+
+    def lookup(self, keys):
+        found = np.asarray([k in self.d for k in keys.tolist()])
+        vals = np.asarray([self.d.get(k, 0) for k in keys.tolist()])
+        return found, vals
+
+
+def _drive_write_path(case, idx, ref, base, fresh):
+    """Run one write path on ``idx`` and ``ref``; returns what the index
+    answered on the way (delete hits, lookups mid-drain)."""
+    out = []
+
+    def insert(k, v, **kw):
+        idx.insert(k, v, **kw)
+        ref.insert(k, v)
+
+    def delete(k):
+        hit = idx.delete(k)
+        np.testing.assert_array_equal(hit, ref.delete(k), err_msg=case)
+        out.append(hit)
+
+    if case == "dups_revivals":
+        delete(base[100:160])
+        dup = np.concatenate([base[100:130], fresh[:40]])
+        insert(np.concatenate([dup, dup]), np.concatenate([dup + 5, dup + 6]))
+        insert(fresh[:40], fresh[:40] + 7)       # pure value updates
+    elif case == "deletes":
+        delete(np.concatenate([base[::4], fresh[:20], base[:40:4]]))
+        delete(base[::4])                        # all gone: no hits
+        insert(base[:200:8], base[:200:8] + 9)   # revives some
+    elif case == "bmat_growth":
+        bcap = int(idx.state.bmat.keys.shape[1])
+        insert(fresh[:200], fresh[:200] + 3, pad_to=bcap)
+        assert int(idx.state.bmat.keys.shape[1]) > bcap
+        lo, hi = int(base[300]), int(base[303])
+        hot = np.arange(lo + 1, hi, max((hi - lo) // 120, 1))[:100]
+        insert(hot, hot + 4)                     # a hotspot into the BMAT
+    elif case == "commit_mid_drain":
+        snap = idx.snapshot(shards=[0])
+        insert(fresh[:150], fresh[:150] + 2)     # logged for the build
+        delete(base[:60])
+        plan = types.SimpleNamespace(action=A_RETRAIN_SHARD, shard=0,
+                                     gmm=None)
+        assert idx.commit(build_plan(plan, snap), replay_cap=8)
+        assert idx.draining                      # parked: log above the cap
+        insert(base[:30], base[:30] + 11)        # appends to the log
+        out.append(idx.lookup(base[:120]))       # old rows still serve
+        while idx.draining:
+            idx.advance_drains(replay_cap=64)
+        assert idx.n_commits == 1
+    elif case == "split_merge":
+        assert idx.split_shard(idx.n_shards - 1)
+        insert(fresh[:100], fresh[:100] + 1)
+        assert idx.merge_shards(0)
+        delete(fresh[:100:3])
+    else:
+        raise ValueError(case)
+    return out
+
+
+@pytest.mark.parametrize("n_shards", [1, 2])
+@pytest.mark.parametrize("case", WRITE_PATHS)
+def test_fused_matches_spline_after_write_paths(case, n_shards):
+    """Every write path leaves a state the fused kernels read exactly as
+    the jnp spline path does: the kernels split the int64 keys they read
+    inside each call, so no write path has a second copy of the keys to
+    keep up to date. radix_bits=14 keeps the stacked state inside the
+    fused guard, so every shard runs the kernels to the end."""
+    base = make_keys(1200, 41, hi=KEY_HI)
+    fresh = np.setdiff1d(make_keys(800, 42, hi=KEY_HI), base)
+    answers, sizes = {}, {}
+    for strat in ("fused", "spline"):
+        cfg = UpLIFConfig(locate=strat, batch_bucket=256, radix_bits=14)
+        idx = ShardedUpLIF(base, base * 3 + 1, cfg, n_shards=n_shards)
+        ref = _Ref(base, base * 3 + 1)
+        out = _drive_write_path(case, idx, ref, base, fresh)
+        assert idx.shard_locate() == (strat,) * idx.n_shards
+        b = idx.boundaries.astype(np.int64)
+        probes = np.concatenate([base[::3], fresh[::2], b, b - 1, b + 1])
+        f, v = idx.lookup(probes)
+        wf, wv = ref.lookup(probes)
+        np.testing.assert_array_equal(f, wf, err_msg=f"{case}/{strat}")
+        np.testing.assert_array_equal(v, wv, err_msg=f"{case}/{strat}")
+        ks, vs = idx.range_query(int(base[250]), int(base[420]), max_out=256)
+        answers[strat] = out + [(f, v), (ks, vs)]
+        sizes[strat] = idx.size
+    assert sizes["fused"] == sizes["spline"]
+    for a, b in zip(answers["fused"], answers["spline"]):
+        for xa, xb in zip(a if isinstance(a, tuple) else (a,),
+                          b if isinstance(b, tuple) else (b,)):
+            np.testing.assert_array_equal(xa, xb, err_msg=case)
+
+
+@pytest.mark.parametrize("case", [
+    "fused_refused_at_build", "fused_refused_after_growth", "split", "merge",
+    "bmat_growth",
+])
+def test_router_runs_one_strategy(case):
+    """A router runs the one strategy it was built with on every shard,
+    held to the fused guard at every shape change: fused where the guard
+    refuses (the stacked radix tables of S = 2 at 2**16 buckets, or a
+    BMAT grown past the rank kernel's VMEM budget) runs and reports spline
+    on every shard, and a split, a merge or a BMAT growth keeps the
+    strategy."""
+    keys = make_keys(1500, 43, hi=KEY_HI)
+    fresh = np.setdiff1d(make_keys(300, 44, hi=KEY_HI), keys)
+    locate, n_shards, radix_bits, want = {
+        "fused_refused_at_build": ("fused", 2, 16, "spline"),
+        "fused_refused_after_growth": ("fused", 1, 14, "spline"),
+        "split": ("binsearch", 2, 16, "binsearch"),
+        "merge": ("binsearch", 3, 16, "binsearch"),
+        "bmat_growth": ("binsearch", 2, 16, "binsearch"),
+    }[case]
+    cfg = UpLIFConfig(locate=locate, batch_bucket=256, radix_bits=radix_bits)
+    idx = ShardedUpLIF(keys, keys + 1, cfg, n_shards=n_shards)
+    if case == "fused_refused_after_growth":
+        assert idx.shard_locate() == ("fused",)
+        assert idx.presize_bmat(2 * kops.MAX_VMEM_KEYS)
+    elif case == "split":
+        assert idx.split_shard(0)
+    elif case == "merge":
+        assert idx.merge_shards(1)
+    elif case == "bmat_growth":
+        bcap = int(idx.state.bmat.keys.shape[1])
+        idx._ensure_bmat_capacity(bcap)
+        assert int(idx.state.bmat.keys.shape[1]) > bcap
+    idx.insert(fresh, fresh + 1)
+    assert idx.shard_locate() == (want,) * idx.n_shards
+    assert idx._static().locate == want
+    probes = np.concatenate([keys[::5], fresh])
+    f, v = idx.lookup(probes)
+    assert f.all() and np.array_equal(v, probes + 1)
 
 
 def test_fused_locate_kernel_is_wired(monkeypatch):
@@ -275,11 +364,11 @@ def test_fused_guard_falls_back_cleanly():
     idx = ShardedUpLIF(keys, keys + 1, UpLIFConfig(locate="fused"),
                        n_shards=2)
     assert idx.shard_locate() == ("spline", "spline")
-    assert "fused" not in idx.locate_options()
-    assert not idx.set_shard_locate(1, "fused")
+    st = idx.state
+    assert not fops.fused_fits(st.slots.keys, st.model, st.bmat)
+    assert idx._static().locate == "spline"
     f, v = idx.lookup(keys[:50])
     assert f.all() and np.array_equal(v, keys[:50] + 1)
-    st = idx.state
     with pytest.raises(ValueError, match="fused"):
         fops.slookup(st, jnp.asarray(keys[:256]), idx._jbounds,
                      static=idx._static()._replace(locate="fused"))

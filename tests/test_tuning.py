@@ -380,6 +380,22 @@ def test_controller_learning_updates_q():
     assert abs(ctl.q[s0][A_RETRAIN_SHARD] - want) < 1e-9
 
 
+def test_import_q_keeps_columns_of_a_wider_stored_row():
+    """A Q-table saved while a sixth action existed (the last column)
+    imports as the five actions there are, each column still the value
+    of the action with that index; a narrower row zero-pads."""
+    ctl = ShardTuningController()
+    wide = [0.5, 1.0, 2.0, 3.0, 4.0, 9.0]
+    ctl.import_q({"1,2,3,0,0,1,2": wide, "0,0,0,0,0,0,0": [1.5, 2.5]})
+    row = ctl.q[(1, 2, 3, 0, 0, 1, 2)]
+    assert len(ACTIONS) == 5 and row.shape == (5,)
+    for a, want in ((A_KEEP, 0.5), (A_RETRAIN_SHARD, 1.0),
+                    (A_SWITCH_BMAT, 2.0), (A_SPLIT_SHARD, 3.0),
+                    (A_MERGE_SHARDS, 4.0)):
+        assert row[a] == want
+    np.testing.assert_array_equal(ctl.q[(0,) * 7], [1.5, 2.5, 0, 0, 0])
+
+
 def test_telemetry_signals_match_measures():
     keys, idx = _router(n=16_000, shards=4)
     rng = np.random.default_rng(4)
